@@ -1360,6 +1360,9 @@ class FileKernelVerifier:
         block = sp.shape
         if block is None and arr_shape is not None:
             block = arr_shape          # whole-array spec
+        elif block is not None:
+            # a None block dim is squeezed: one element of that axis
+            block = tuple(1 if d is None else d for d in block)
         return block, dtype, arr_shape, sp.lineno
 
     def _check_alignment(self, role: str, i: int, block: tuple,

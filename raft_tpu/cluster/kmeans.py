@@ -28,7 +28,7 @@ import numpy as np
 from raft_tpu.distance.types import DistanceType
 from raft_tpu.distance.fused_l2_nn import _fused_l2_nn
 from raft_tpu.utils.math import round_up_to_multiple
-from raft_tpu.utils.precision import dist_dot
+from raft_tpu.utils.precision import argmax_exact, dist_dot
 
 
 @dataclasses.dataclass
@@ -115,7 +115,7 @@ def _predict_metric_labels(x, centers, metric_val: int, batch_rows: int):
     def body(_, batch):
         bn = batch / jnp.maximum(jnp.linalg.norm(batch, axis=1, keepdims=True), 1e-30)
         scores = dist_dot(bn, cn.T)
-        lab = jnp.argmax(scores, axis=1).astype(jnp.int32)
+        lab = argmax_exact(scores, axis=1)
         return None, (lab, 1.0 - jnp.max(scores, axis=1))
 
     _, (labels, dists) = jax.lax.scan(body, None, xb)

@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from raft_tpu.comms.compat import axis_size
+from jax.lax import axis_size
 
 
 def allreduce(x, axis_name: str, op: str = "sum"):

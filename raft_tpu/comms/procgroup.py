@@ -327,8 +327,8 @@ def _proc_worker_main(rank: int, req_q, resp_q, algo: str, slow_s: float,
     (``os._exit``) with no response — the honest SIGKILL analog."""
     if platform:
         # belt-and-braces: the parent already swapped the env before
-        # spawn, but backend selection must never fall through to a
-        # hung TPU plugin inside a fabric worker
+        # spawn; a fabric worker runs on the host and must never reach
+        # for the chip, which its parent may hold
         os.environ.setdefault("JAX_PLATFORMS", platform)
     if obs_mode is not None:
         # inherit the PARENT's resolved obs mode, not just the env: a
@@ -402,8 +402,9 @@ class ProcGroup:
     Children inherit the parent environment minus the
     ``--xla_force_host_platform_device_count`` test flag (a worker
     needs one device, not eight virtual ones) and with
-    ``JAX_PLATFORMS`` pinned to ``platform`` (default ``cpu`` — a
-    fabric worker must never block on a hung TPU plugin probe).
+    ``JAX_PLATFORMS`` pinned to ``platform`` (default ``cpu``: workers
+    run on the host; a chip belongs to one process, and the parent may
+    hold it. Pinning one worker to each chip is a feature of its own).
     """
 
     def __init__(self, n_workers: int, algo: str = "brute_force",

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from raft_tpu.comms.compat import shard_map
+from jax import shard_map
 
 from raft_tpu import obs
 from raft_tpu import plan as plan_mod
@@ -160,11 +160,16 @@ def sharded_knn(
     # turns every pad row into the worst-possible sentinel
     k_local = int(min(k + n_pad, shard_rows)) if n_pad else int(k)
 
+    # a scan tile that divides the shard: a padded copy of the shard
+    # would double its device memory
+    tile = next(t for t in range(min(shard_rows, 8192), 0, -1)
+                if shard_rows % t == 0)
+
     def local(q, db_shard, *rest):
         rank = jax.lax.axis_index(axis_name)
         d, i = brute_force._search(
             q, db_shard, None, None, None, k_local, int(metric),
-            float(metric_arg), int(min(shard_rows, 8192)),
+            float(metric_arg), int(tile),
         )
         i = i + (rank * shard_rows).astype(i.dtype)
         if n_pad:
@@ -560,22 +565,27 @@ def sharded_ivf_pq_build(
     ITS row shard under ``shard_map`` — the FLOP-heavy stage (coarse
     assignment + per-subspace argmin) scales linearly over the mesh, the
     reference's multi-GPU build split (raft-dask builds per-worker parts
-    against shared quantizers). The per-shard codes are all-gathered and
-    packed into the global list layout; at real DEEP-1B scale the gather
-    becomes a list-owner reduce-scatter instead (each device keeps only
-    its C/S lists — see ``sharded_ivf_pq_search``'s in_specs), which this
-    single-host rehearsal cannot exercise.
+    against shared quantizers). Each device then packs the lists it owns
+    in ``sharded_ivf_pq_search`` (its C/S contiguous lists) from the
+    all-gathered codes, so the list arrays come back list-sharded and no
+    device ever holds all of them; at DEEP-1B scale the all-gather of
+    the codes would become an all-to-all.
 
     Returns a regular ``ivf_pq.Index`` with GLOBAL row ids; pass it to
     ``sharded_ivf_pq_search`` to search list-sharded over the mesh.
     """
     from raft_tpu.neighbors import ivf_pq
 
-    dataset = jnp.asarray(dataset)
     n, dim = dataset.shape
     nshards = mesh.shape[axis_name]
     if n % nshards != 0:
         raise ValueError(f"dataset rows {n} not divisible by mesh axis {nshards}")
+    if int(params.n_lists) % nshards != 0:
+        raise ValueError(f"n_lists {params.n_lists} not divisible by mesh "
+                         f"axis {nshards}")
+    # row shards straight onto their devices: the dataset never sits
+    # whole on one chip (a no-op when it is already placed so)
+    dataset = jax.device_put(dataset, NamedSharding(mesh, P(axis_name, None)))
 
     frac = float(params.kmeans_trainset_fraction)
     if 0 < frac < 1.0 and int(n * frac) >= int(params.n_lists):
@@ -584,9 +594,17 @@ def sharded_ivf_pq_build(
         trainset = dataset
     quant = ivf_pq._quantizer_index(params, trainset, dim)
 
+    rows = n // nshards
+    # each device encodes its shard in row chunks, so the encode's
+    # transients (rotated rows, residuals) stay at a chunk's size
+    chunk = next(c for c in range(min(rows, 1 << 20), 0, -1)
+                 if rows % c == 0)
+
     def local_encode(part):
-        labels, packed = ivf_pq.encode(quant, part)
-        return labels, packed
+        labels, packed = jax.lax.map(
+            lambda blk: ivf_pq.encode(quant, blk),
+            part.reshape(rows // chunk, chunk, dim))
+        return labels.reshape(rows), packed.reshape(rows, -1)
 
     fn = shard_map(
         local_encode,
@@ -597,19 +615,42 @@ def sharded_ivf_pq_build(
     )
     labels, packed = jax.jit(fn)(dataset)
 
-    import numpy as np
     from raft_tpu.neighbors.ivf_flat import _aligned_cap, _pack_lists
 
-    ids = jnp.arange(n, dtype=jnp.int32)
     counts = np.bincount(np.asarray(labels), minlength=quant.n_lists)
     cap = _aligned_cap(int(counts.max()))
-    codes_packed, indices, list_sizes = _pack_lists(
-        packed, labels, ids, quant.n_lists, cap
-    )
-    rec_norms = ivf_pq._rec_norms(
-        codes_packed, quant.pq_centers, int(params.codebook_kind),
-        quant.pq_dim, int(params.pq_bits),
-    )
+    lists = quant.n_lists // nshards
+
+    def local_pack(lab, codes):
+        # rows of other devices' lists get label ``lists``: dropped
+        lab = jax.lax.all_gather(lab, axis_name, tiled=True)
+        codes = jax.lax.all_gather(codes, axis_name, tiled=True)
+        lo = jax.lax.axis_index(axis_name) * lists
+        mine = (lab >= lo) & (lab < lo + lists)
+        return _pack_lists(codes, jnp.where(mine, lab - lo, lists),
+                           jnp.arange(n, dtype=jnp.int32), lists, cap)
+
+    codes_packed, indices, list_sizes = jax.jit(shard_map(
+        local_pack,
+        mesh=mesh,
+        in_specs=(P(axis_name), P(axis_name, None)),
+        out_specs=(P(axis_name),) * 3,
+        check_vma=False,
+    ))(labels, packed)
+    # each device scores its own lists: a scan over the list axis of the
+    # list-sharded codes would gather them whole onto every device (4 x
+    # v5e at 40M rows: 9.6 GB, RESOURCE_EXHAUSTED)
+    per_cluster = (int(params.codebook_kind)
+                   == ivf_pq.codebook_gen.PER_CLUSTER)
+    rec_norms = jax.jit(shard_map(
+        lambda codes, books: ivf_pq._rec_norms(
+            codes, books, int(params.codebook_kind), quant.pq_dim,
+            int(params.pq_bits)),
+        mesh=mesh,
+        in_specs=(P(axis_name), P(axis_name) if per_cluster else P()),
+        out_specs=P(axis_name),
+        check_vma=False,
+    ))(codes_packed, quant.pq_centers)
     import dataclasses as _dc
 
     return ivf_pq._attach_cache(_dc.replace(

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from raft_tpu.utils.math import round_up_to_multiple
-from raft_tpu.utils.precision import dist_dot
+from raft_tpu.utils.precision import argmin_exact, dist_dot
 
 
 def fused_l2_nn_argmin(
@@ -56,7 +56,7 @@ def _fused_l2_nn(x, y, sqrt: bool, tile_n: int):
         dot = dist_dot(x, y.T)
         yn = jnp.sum(y * y, axis=1)
         d2 = jnp.maximum(xn[:, None] + yn[None, :] - 2.0 * dot, 0.0)
-        idx = jnp.argmin(d2, axis=1).astype(jnp.int32)
+        idx = argmin_exact(d2, axis=1)
         val = jnp.take_along_axis(d2, idx[:, None], axis=1)[:, 0]
         return (jnp.sqrt(val) if sqrt else val), idx
 
@@ -73,7 +73,7 @@ def _fused_l2_nn(x, y, sqrt: bool, tile_n: int):
         d2 = jnp.maximum(xn[:, None] + yn[None, :] - 2.0 * dot, 0.0)
         col = jnp.arange(tile_n) + t * tile_n
         d2 = jnp.where(col[None, :] < n, d2, jnp.inf)
-        tile_idx = jnp.argmin(d2, axis=1)
+        tile_idx = argmin_exact(d2, axis=1)
         tile_val = jnp.take_along_axis(d2, tile_idx[:, None], axis=1)[:, 0]
         take = tile_val < best_val
         best_val = jnp.where(take, tile_val, best_val)
@@ -126,7 +126,7 @@ def masked_l2_nn_argmin(
     yn = jnp.sum(yw * yw, axis=1)
     d2 = jnp.maximum(xn[:, None] + yn[None, :] - 2.0 * dot, 0.0)
     d2 = jnp.where(mask, d2, jnp.inf)
-    idx = jnp.argmin(d2, axis=1).astype(jnp.int32)
+    idx = argmin_exact(d2, axis=1)
     val = jnp.take_along_axis(d2, idx[:, None], axis=1)[:, 0]
     if sqrt:
         val = jnp.sqrt(val)

@@ -13,6 +13,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from raft_tpu.utils.precision import argmax_exact, argmin_exact
+
 
 def gather(matrix, row_indices) -> jax.Array:
     """Select rows (reference matrix/gather.cuh)."""
@@ -39,11 +41,11 @@ def slice_matrix(matrix, row_start: int, row_end: int, col_start: int = 0, col_e
 
 def argmax(matrix) -> jax.Array:
     """Per-row argmax (reference matrix/argmax.cuh)."""
-    return jnp.argmax(jnp.asarray(matrix), axis=1).astype(jnp.int32)
+    return argmax_exact(matrix, axis=1)
 
 
 def argmin(matrix) -> jax.Array:
-    return jnp.argmin(jnp.asarray(matrix), axis=1).astype(jnp.int32)
+    return argmin_exact(matrix, axis=1)
 
 
 def col_wise_sort(matrix, ascending: bool = True):

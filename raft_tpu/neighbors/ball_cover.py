@@ -38,7 +38,7 @@ import numpy as np
 from raft_tpu.distance.types import DistanceType, resolve_metric
 from raft_tpu.distance.pairwise import pairwise_distance
 from raft_tpu.neighbors.ivf_flat import _aligned_cap, _pack_lists
-from raft_tpu.utils.precision import dist_dot
+from raft_tpu.utils.precision import argmin_exact, dist_dot
 
 _SUPPORTED = {
     DistanceType.L2SqrtExpanded,
@@ -110,7 +110,7 @@ def build(
             dataset, C, metric=DistanceType.L2Expanded, seed=seed
         )
         d_pl = pairwise_distance(dataset, landmarks, metric)  # [n, C] true
-        labels = jnp.argmin(d_pl, axis=1).astype(jnp.int32)
+        labels = argmin_exact(d_pl, axis=1)
         dist_to_lm = jnp.min(d_pl, axis=1)
 
         # graft-lint: allow-host-sync build list capacity must be concrete to allocate

@@ -14,8 +14,11 @@ from the receiving side), scores them, and merges them into its list
 with a unique top-K — all static shapes, no atomics. Reverse edges come
 from the same sort-scatter pack used by the IVF builds; the bloom-filter
 "already tried" tracking is replaced by per-iteration random sampling of
-the 2-hop columns, which converges the same way (candidates are
-re-drawn, duplicates cost only a re-score).
+the 2-hop columns, biased to near ranks, and of the reverse edges
+(candidates are re-drawn, duplicates cost only a re-score). The
+initial lists come from two coarse balanced k-means clusterings plus a
+few random links, so the join refines local structure from the first
+iteration instead of finding it by chance (:func:`_init_graph`).
 
 Rebuilt for the memory hierarchy (the TPU-KNN treatment, ROADMAP item
 7): the join is **sample-then-gather** — the sampled columns select
@@ -79,8 +82,9 @@ class IndexParams:
     max_iterations: int = 20
     termination_threshold: float = 0.0001
     metric: DistanceType = DistanceType.L2Expanded
-    # candidates pulled per node per iteration (the reference's
-    # max_candidates analog; sampled from the 2-hop pool)
+    # candidates joined per node per iteration, beyond the list size K
+    # (the reference's max_candidates analog): n_candidates + K in all,
+    # _REV_COLS reverse edges and the rest sampled two-hop columns
     n_candidates: int = 128
     seed: int = 0
     # join backend: "auto" = dispatch table (op key "graph_join"; the
@@ -199,12 +203,13 @@ def _init_block(data, norms, init_i, start, *, rows: int, ip: bool):
 @functools.partial(
     jax.jit, static_argnames=("rows", "ip", "impl", "tile_b"),
 )
-def _join_block(data, norms, graph_d, graph_i, pool, rev_i, cols, start,
-                *, rows: int, ip: bool, impl: str, tile_b: int):
+def _join_block(data, norms, graph_d, graph_i, pool, rev_i, cols, rcols,
+                start, *, rows: int, ip: bool, impl: str, tile_b: int):
     """One local-join dispatch over node rows [start, start+rows).
 
     Sample-then-gather: ``cols`` selects (pool slot, neighbor slot)
-    pairs, so only the [rows, S] sampled two-hop entries are gathered —
+    pairs and ``rcols`` reverse-list slots, so only the sampled two-hop
+    and reverse entries are gathered —
     the full [rows, 2K, K] two-hop tensor is never formed. Row
     independent (the blocked cover is bitwise what one unblocked
     dispatch would produce), which is what lets the OOM ladder split it.
@@ -215,7 +220,8 @@ def _join_block(data, norms, graph_d, graph_i, pool, rev_i, cols, start,
     gd = jax.lax.dynamic_slice(graph_d, (start, 0), (rows, K))
     gi = jax.lax.dynamic_slice(graph_i, (start, 0), (rows, K))
     pool_b = jax.lax.dynamic_slice(pool, (start, 0), (rows, 2 * K))
-    rev_b = jax.lax.dynamic_slice(rev_i, (start, 0), (rows, K))
+    rev_b = jnp.take(jax.lax.dynamic_slice(rev_i, (start, 0), (rows, K)),
+                     rcols, axis=1)
 
     sel = cols // K                                      # [S] pool slot
     off = cols % K                                       # [S] neighbor slot
@@ -246,6 +252,69 @@ def _join_block(data, norms, graph_d, graph_i, pool, rev_i, cols, start,
         new_d, new_i = _merge_topk_unique(gd, gi, cand_d, cand, K)
     n_updates = jnp.sum(new_i != gi, dtype=jnp.int32)
     return new_d, new_i, n_updates
+
+
+# nodes per cluster of the initial graph's coarse clusterings, and the
+# random (long-range) slots of each initial list
+_INIT_CLUSTER_ROWS = 256
+_INIT_RANDOM = 16
+# reverse-edge columns joined per iteration (of K); the rest of the
+# candidate budget goes to two-hop columns
+_REV_COLS = 32
+
+
+def _init_graph(data, n: int, K: int, key, ip: bool, seed: int):
+    """Initial candidate lists: random members of the node's own cluster
+    in two independent balanced k-means clusterings into ~n/256 clusters
+    (a two-tree forest: a neighbor cut off by one partition's boundary is
+    often inside the other's), plus ``_INIT_RANDOM`` random nodes (the
+    long-range links that let two-hop joins cross clusters; without
+    them the clusters stay islands). From a random init alone, recall
+    per iteration fell with n: 20 iterations gave search recall@10 0.97
+    at 50k rows, 0.87 at 200k (CPU), 0.61 at 1M (v5e). Small n keeps the
+    all-random init."""
+    from raft_tpu.cluster import kmeans_balanced
+
+    keys = jax.random.split(key, 3)
+    rnd = jax.random.randint(keys[0], (n, K), 0, n).astype(jnp.int32)
+    C = n // _INIT_CLUSTER_ROWS
+    if C < 2 or K <= _INIT_RANDOM:
+        return rnd
+    metric = DistanceType.InnerProduct if ip else DistanceType.L2Expanded
+    # train on >= 32 rows per cluster (at least 64k rows)
+    step = max(1, n // max(1 << 16, 32 * C))
+    h = (K - _INIT_RANDOM) // 2
+    parts = []
+    for t, width in enumerate((h, K - _INIT_RANDOM - h)):
+        params = kmeans_balanced.KMeansBalancedParams(
+            n_clusters=C, n_iters=10, seed=seed + t, metric=metric)
+        labels = kmeans_balanced.predict(
+            params, kmeans_balanced.fit(params, data[t::step]), data)
+        order = jnp.argsort(labels, stable=True).astype(jnp.int32)
+        counts = jnp.bincount(labels, length=C)
+        starts = (jnp.cumsum(counts) - counts)[labels]
+        pick = jax.random.randint(keys[1 + t], (n, width), 0, 1 << 30)
+        parts.append(order[starts[:, None] + pick % counts[labels][:, None]])
+    return jnp.concatenate(parts + [rnd[:, K - _INIT_RANDOM:]], axis=1)
+
+
+def _sample_cols(key, S: int, K: int):
+    """This iteration's ``S`` two-hop columns (``pool slot * K +
+    neighbor slot``), shared by every node. Forward lists are sorted
+    nearest first, so ranks are drawn as ``floor(K * u**2)``: a
+    neighbor's near neighbors come up most often, far ranks still
+    appear. A quarter of the pool slots are reverse edges (unsorted,
+    drawn uniformly). Against uniform columns this raised graph
+    recall@64 at 200k rows from 0.36 to 0.56 (CPU, random init)."""
+    k_p, k_o, k_r, k_s = jax.random.split(key, 4)
+
+    def rank(k):
+        u = jax.random.uniform(k, (S,))
+        return jnp.minimum(jnp.floor(K * u * u), K - 1).astype(jnp.int32)
+
+    rev = jax.random.uniform(k_r, (S,)) < 0.25
+    slot = jnp.where(rev, K + jax.random.randint(k_s, (S,), 0, K), rank(k_p))
+    return slot * K + rank(k_o)
 
 
 def _blocked(fn, n: int, block: int):
@@ -314,6 +383,7 @@ def _build(params: IndexParams, data, n: int) -> Index:
     key = jax.random.PRNGKey(params.seed)
 
     S = int(params.n_candidates)
+    R = min(_REV_COLS, K)
     impl = _resolve_join_impl(str(params.join_impl), S + K, K, d, ip)
     kind, _, tile = impl.partition(":")
     tile_b = int(tile) if tile else 0
@@ -332,10 +402,10 @@ def _build(params: IndexParams, data, n: int) -> Index:
             b = int(tuning.budget("graph_join_rows", _DEF_BLOCK_ROWS))
         return max(1, b)
 
-    # init: random neighbors, exactly scored + deduped, blocked like the
-    # join (the [rows, K, d] init gather is the same transient class)
+    # init lists (_init_graph), exactly scored + deduped, blocked like
+    # the join (the [rows, K, d] init gather is the same transient class)
     key, k0 = jax.random.split(key)
-    init_i = jax.random.randint(k0, (n, K), 0, n).astype(jnp.int32)
+    init_i = _init_graph(data, n, K, k0, ip, int(params.seed))
     init_i = jnp.where(init_i == jnp.arange(n)[:, None], (init_i + 1) % n,
                        init_i)
     parts = _blocked(
@@ -356,11 +426,13 @@ def _build(params: IndexParams, data, n: int) -> Index:
             pool = jnp.concatenate([graph_i, rev_i], axis=1)   # [n, 2K]
             # fresh column draw per iteration — the bloom-filter
             # "new vs old" bookkeeping collapses into re-sampling
-            cols = jax.random.randint(kit, (S,), 0, 2 * K * K)
+            kit, krev = jax.random.split(kit)
+            cols = _sample_cols(kit, S + K - R, K)
+            rcols = jax.random.permutation(krev, K)[:R]
             parts = _blocked(
                 lambda s, r: _join_block(
-                    data, norms, graph_d, graph_i, pool, rev_i, cols, s,
-                    rows=r, ip=ip, impl=kind, tile_b=tile_b),
+                    data, norms, graph_d, graph_i, pool, rev_i, cols, rcols,
+                    s, rows=r, ip=ip, impl=kind, tile_b=tile_b),
                 n, block_rows(),
             )
             graph_d = jnp.concatenate([p[0] for p in parts], axis=0)

@@ -6,8 +6,7 @@ update, and classified error lands in a fixed-size ring buffer
 dumpable as JSONL on demand (:func:`dump`) and dumps ITSELF — once per
 process — when :func:`on_error` sees a classified ``fatal`` or
 ``dead_backend`` failure, so a wedged TPU job leaves a post-mortem
-artifact under ``RAFT_TPU_OBS_DIR`` the same way ``core/exit_guard``
-leaves an honest exit code.
+artifact under ``RAFT_TPU_OBS_DIR``.
 
 Dump grammar: one JSON object per line, every line carrying ``t``
 (unix seconds) and ``kind``:

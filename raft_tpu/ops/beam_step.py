@@ -247,9 +247,12 @@ def _beam_step_kernel(
                 acc = acc + (
                     b.astype(jnp.bfloat16) * qr[:, j, :]
                 ).astype(jnp.float32)
+            # HIGHEST: a default f32 dot rounds ``acc`` to bf16 before
+            # the segment sum (seg is 0/1, exact in any pass)
             dots = jax.lax.dot_general(
                 acc, seg,
                 dimension_numbers=(((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32,
             )                                      # [G, deg]
             # load full 128-aligned regions, slice statically after

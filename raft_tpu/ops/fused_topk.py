@@ -19,8 +19,11 @@ docs/kernels.md §candidate-buffers):
 
 ``exact``
     k-pass min extraction (the warp-queue analog) — emits the tile's
-    EXACT top-k, so the downstream merge is exact end to end (ids
-    bitwise vs the XLA oracle). Extraction cost grows with k: eligible
+    EXACT top-k of its own distances, so the downstream merge is exact
+    end to end: each rank's id carries the oracle's distance at that
+    rank (ids may swap only between f32 near-ties, which the tile
+    matmul sums in another order; exact ties go to the lower id).
+    Extraction cost grows with k: eligible
     for k <= 128.
 ``fold``
     R-deep per-lane partial reduction (TPU-KNN's approximate-then-exact
@@ -73,11 +76,14 @@ def _extract_exact(dist, col, k: int, outd_ref, outi_ref):
             dist = jnp.where(col == pos[:, None], jnp.inf, dist)
 
 
-def fold_lane_stacks(dist, ids, R: int):
+def fold_lane_stacks(dist, chunk_ids, R: int):
     """The shared R-deep per-lane fold (TPU-KNN's PartialReduce core):
     lane b keeps its R smallest (value, id) pairs as a sorted
-    compare-swap cascade over the T//128 lane chunks of ``dist``/
-    ``ids`` [G, T]. Returns (stack_d, stack_i) — R arrays of [G, 128]
+    compare-swap cascade over the T//128 lane chunks of ``dist`` [G, T].
+    ``chunk_ids(c)`` gives chunk c's ids, broadcastable to [G, 128]: the
+    ids are built per chunk because Mosaic refuses a lane slice of a
+    [G, T] iota or broadcast (a compiler abort, found compiling for a
+    described v5e). Returns (stack_d, stack_i) — R arrays of [G, 128]
     each, sorted per lane, +inf/-1 in unfilled slots. Used by both
     fused kernels (this module's brute-force tiles and
     ops.ivf_scan's fold extraction) so the fold semantics and any
@@ -88,7 +94,7 @@ def fold_lane_stacks(dist, ids, R: int):
     stack_i = [jnp.full((G, 128), _INVALID, jnp.int32) for _ in range(R)]
     for c in range(nch):
         nd = dist[:, c * 128:(c + 1) * 128]
-        ni = ids[:, c * 128:(c + 1) * 128]
+        ni = chunk_ids(c)
         for r in range(R):
             swap = nd < stack_d[r]
             sd, si = stack_d[r], stack_i[r]
@@ -99,11 +105,15 @@ def fold_lane_stacks(dist, ids, R: int):
     return stack_d, stack_i
 
 
-def _extract_fold(dist, col, R: int, outd_ref, outi_ref):
+def _extract_fold(dist, col0, R: int, outd_ref, outi_ref):
     """R-deep per-lane fold over [G, T]: the R*128 survivors are
     written out UNEXTRACTED — selection happens in the cross-tile
-    merge (TPU-KNN's approximate-then-exact partial reduction)."""
-    stack_d, stack_i = fold_lane_stacks(dist, col, R)
+    merge (TPU-KNN's approximate-then-exact partial reduction).
+    ``col0`` is the tile's first global column."""
+    G = dist.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (G, 128), 1)
+    stack_d, stack_i = fold_lane_stacks(
+        dist, lambda c: lane + (col0 + c * 128), R)
     for r in range(R):
         outd_ref[:, r * 128:(r + 1) * 128] = stack_d[r]
         outi_ref[:, r * 128:(r + 1) * 128] = jnp.where(
@@ -120,9 +130,14 @@ def _fused_kernel(q_ref, x_ref, *refs, k: int, metric_kind: int,
     j = pl.program_id(1)
     q = q_ref[...]                                      # [TQ, d] mm dtype
     x = x_ref[...]                                      # [TN, d] mm dtype
+    # f32 operands ask for full f32 contraction: the exact arm's ids
+    # must match an f32 oracle, which single-pass bf16 MXU rounding
+    # (~1e-3 relative) would not
     dots = jax.lax.dot_general(
         q, x,
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=(jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
+                   else None),
         preferred_element_type=jnp.float32,
     )                                                   # [TQ, TN] f32
     G, T = dots.shape
@@ -138,7 +153,7 @@ def _fused_kernel(q_ref, x_ref, *refs, k: int, metric_kind: int,
     col = jax.lax.broadcasted_iota(jnp.int32, (G, T), 1) + j * tile_n
     dist = jnp.where(col < n, dist, jnp.inf)            # mask pad rows
     if variant == "fold":
-        _extract_fold(dist, col, fold_r, outd_ref, outi_ref)
+        _extract_fold(dist, j * tile_n, fold_r, outd_ref, outi_ref)
     else:
         _extract_exact(dist, col, k, outd_ref, outi_ref)
 
@@ -208,7 +223,7 @@ def fused_topk(
     are negated scores — negate back after. Rows short of k valid
     candidates come back (+inf, -1).
 
-    ``variant``: "exact" (bitwise-exact ids, k <= 128) | "fold"
+    ``variant``: "exact" (exact top-k up to f32 near-ties, k <= 128) | "fold"
     (R-deep lane fold, k <= 256; bounded per-tile loss recovered by the
     exact cross-tile merge). Tile geometry defaults to the
     expression-derived :func:`tile_geometry`; callers resolving through
@@ -301,23 +316,26 @@ def _fused_topk_tiles(queries, dataset, norms=None, qaux=None, *, k: int,
         _fused_kernel, k=k, metric_kind=metric_kind, variant=variant,
         fold_r=fold_depth(k), n=n, tile_n=tile_n, has_norms=has_norms,
     )
+    # candidates land as [nt, M, C] with block (None, tile_q, C): the
+    # block's last dim equals the array's, so the narrow exact-arm width
+    # C = k needs no lane padding (Mosaic's (8, 128)-or-full block rule)
     out_d, out_i = pl.pallas_call(
         kernel,
         grid=(mq, nt),
         in_specs=in_specs,
         out_specs=[
-            # graft-lint: allow-tile-align exact-arm candidate width C=k is deliberately narrow — lane-padding it to 128 would multiply the kernel's whole HBM output by 128/k, the very traffic the fusion removes (docs/kernels.md §candidate-buffers); accepted relayout, revalidate when a chip answers (r6)
-            pl.BlockSpec((tile_q, C), lambda i, j: (i, j)),
-            # graft-lint: allow-tile-align same narrow candidate buffer as the distance output above
-            pl.BlockSpec((tile_q, C), lambda i, j: (i, j)),
+            pl.BlockSpec((None, tile_q, C), lambda i, j: (j, i, 0)),
+            pl.BlockSpec((None, tile_q, C), lambda i, j: (j, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((mq * tile_q, nt * C), jnp.float32),
-            jax.ShapeDtypeStruct((mq * tile_q, nt * C), jnp.int32),
+            jax.ShapeDtypeStruct((nt, mq * tile_q, C), jnp.float32),
+            jax.ShapeDtypeStruct((nt, mq * tile_q, C), jnp.int32),
         ],
         interpret=interpret,
     )(*inputs)
-    return out_d, out_i
+    # [nt, M, C] -> [M, nt*C], tile-major per row (the merge's layout)
+    flat = lambda a: jnp.transpose(a, (1, 0, 2)).reshape(mq * tile_q, nt * C)
+    return flat(out_d), flat(out_i)
 
 
 # ---------------------------------------------------------------------------

@@ -124,9 +124,13 @@ def _join_kernel(*refs, K: int, Kp: int, Cp: int, tile_b: int, ip: bool,
     for b in range(tile_b):
         cb = cv_ref[b * Cp:(b + 1) * Cp, :]            # [Cp, d]
         qb = q_ref[b:b + 1, :]                         # [1, d]
+        # full f32 contraction: the TPU's default f32 dot is one bf16
+        # pass (~1e-3 relative), and the L2 form qn + cn - 2 q.c cancels
+        # that error into the near-neighbor distances the merge ranks
         dots = jax.lax.dot_general(
             qb, cb,
             dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )                                              # [1, Cp]
         if ip:

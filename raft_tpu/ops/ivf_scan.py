@@ -177,9 +177,8 @@ def _extract_fold(dist, ids_row, cap: int, outd_ref, outi_ref, R: int):
     when > R of the list's top-k share a lane."""
     from raft_tpu.ops.fused_topk import fold_lane_stacks
 
-    G = dist.shape[0]
-    ids = jnp.broadcast_to(ids_row[None, :], (G, cap))
-    stack_d, stack_i = fold_lane_stacks(dist, ids, R)
+    stack_d, stack_i = fold_lane_stacks(
+        dist, lambda c: ids_row[c * 128:(c + 1) * 128][None, :], R)
     for r in range(R):
         outd_ref[0, :, r * 128:(r + 1) * 128] = stack_d[r]
         outi_ref[0, :, r * 128:(r + 1) * 128] = jnp.where(

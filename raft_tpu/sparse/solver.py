@@ -22,6 +22,7 @@ import numpy as np
 
 from raft_tpu.linalg.lanczos import lanczos_eigsh  # re-export (sparse/solver/lanczos.cuh)
 from raft_tpu.sparse.types import COO, CSR, csr_to_coo
+from raft_tpu.utils.precision import argmin_exact
 
 __all__ = ["mst", "connected_components", "lanczos_eigsh", "connect_components"]
 
@@ -186,7 +187,7 @@ def connect_components(
         same = colors[r0:r1, None] == colors[None, :]
         d = jnp.where(same, jnp.inf, d)
         best_d.append(jnp.min(d, axis=1))
-        best_j.append(jnp.argmin(d, axis=1))
+        best_j.append(argmin_exact(d, axis=1))
     bd = jnp.concatenate(best_d)
     bj = jnp.concatenate(best_j)
     # lightest outgoing edge per component (segment-min, like Borůvka pass)

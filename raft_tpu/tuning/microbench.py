@@ -191,12 +191,9 @@ def bench_ivf_scan(key: Dict, candidates: List[str],
             n_probes=n_probes, scan_impl=impl,
             local_recall_target=0.95 if approx else 1.0,
         )
-        try:
-            times[impl] = _median_ms(
-                lambda sp=sp: ivf_flat.search(sp, index, queries, k), reps
-            )
-        except Exception:  # noqa: BLE001 - impl unavailable on backend
-            continue
+        times[impl] = _median_ms(
+            lambda sp=sp: ivf_flat.search(sp, index, queries, k), reps
+        )
     return times, key
 
 
@@ -272,10 +269,7 @@ def bench_scan_extract(key: Dict, candidates: Optional[List[str]] = None,
         return merge_topk(pool_d, pool_i, k, True)
 
     for arm in candidates:
-        try:
-            times[arm] = _median_ms(lambda arm=arm: run(arm), reps)
-        except Exception:  # noqa: BLE001 - arm unavailable on backend
-            continue
+        times[arm] = _median_ms(lambda arm=arm: run(arm), reps)
     return times
 
 
@@ -312,12 +306,9 @@ def bench_fused_topk(key: Dict, candidates: Optional[List[str]] = None,
         arm = impl
         if interpret and impl.startswith("fused"):
             arm = impl + ":interpret"
-        try:
-            times[impl] = _median_ms(
-                lambda arm=arm: brute_force.search(index, q, k, impl=arm),
-                reps)
-        except Exception:  # noqa: BLE001 - impl unavailable on backend
-            continue
+        times[impl] = _median_ms(
+            lambda arm=arm: brute_force.search(index, q, k, impl=arm),
+            reps)
     return times
 
 
@@ -365,14 +356,11 @@ def bench_graph_join(key: Dict, candidates: Optional[List[str]] = None,
         kind, _, tile = impl.partition(":")
         if kind.startswith("pallas") and interpret:
             kind = "pallas_interpret"
-        try:
-            times[impl] = _median_ms(
-                lambda kind=kind, tile=tile: _join_block(
-                    data, norms, graph_d, graph_i, pool, rev_i, cols,
-                    start0, rows=rows, ip=False, impl=kind,
-                    tile_b=int(tile) if tile else 0), reps)
-        except Exception:  # noqa: BLE001 - impl unavailable on backend
-            continue
+        times[impl] = _median_ms(
+            lambda kind=kind, tile=tile: _join_block(
+                data, norms, graph_d, graph_i, pool, rev_i, cols,
+                start0, rows=rows, ip=False, impl=kind,
+                tile_b=int(tile) if tile else 0), reps)
     return times
 
 
@@ -427,14 +415,11 @@ def bench_beam_step(key: Dict, candidates: Optional[List[str]] = None,
             g = int(impl.split(":", 1)[1])
         except (IndexError, ValueError):
             continue
-        try:
-            times[impl] = _median_ms(
-                lambda g=g: beam_merge_step(
-                    bd, bi, be, qrep=qrep, pack=pack, parents=parents,
-                    deg=deg, d=d, width=width, g=g,
-                    interpret=interpret), reps)
-        except Exception:  # noqa: BLE001 - tile unavailable on backend
-            continue
+        times[impl] = _median_ms(
+            lambda g=g: beam_merge_step(
+                bd, bi, be, qrep=qrep, pack=pack, parents=parents,
+                deg=deg, d=d, width=width, g=g,
+                interpret=interpret), reps)
     return times
 
 
@@ -519,27 +504,24 @@ def bench_pq_scan(key: Dict, candidates: List[str],
             n_lists=n_lists, pq_bits=4, pq_dim=pq_dim, kmeans_n_iters=4,
             cache_decoded=True, cache_dtype=kind,
         )
-        try:
-            index = ivf_pq.build(params, data)
-            if index.cache_kind != kind:
-                continue  # budget-gated out: not a competitor here
-            key.setdefault("cap", int(index.indices.shape[1]))
-            key.setdefault("rot", int(index.rot_dim))
-            key.setdefault("pq_bits", 4)
-            sp = ivf_pq.SearchParams(n_probes=n_probes)
-            if kind == "rabitq":
-                rr_rec = {}
-                for rr in _RABITQ_RATIOS:
-                    _, ids = ivf_pq.search_refined(sp, index, queries, k,
-                                                   refine_ratio=rr)
-                    rr_rec[rr] = _pq_recall(ids, want)
-                built[kind] = (index, sp, rr_rec)
-            else:
-                _, ids = ivf_pq.search(sp, index, queries, k)
-                recalls[kind] = _pq_recall(ids, want)
-                built[kind] = (index, sp, None)
-        except Exception:  # noqa: BLE001 - kind unavailable on backend
-            continue
+        index = ivf_pq.build(params, data)
+        if index.cache_kind != kind:
+            continue  # budget-gated out: not a competitor here
+        key.setdefault("cap", int(index.indices.shape[1]))
+        key.setdefault("rot", int(index.rot_dim))
+        key.setdefault("pq_bits", 4)
+        sp = ivf_pq.SearchParams(n_probes=n_probes)
+        if kind == "rabitq":
+            rr_rec = {}
+            for rr in _RABITQ_RATIOS:
+                _, ids = ivf_pq.search_refined(sp, index, queries, k,
+                                               refine_ratio=rr)
+                rr_rec[rr] = _pq_recall(ids, want)
+            built[kind] = (index, sp, rr_rec)
+        else:
+            _, ids = ivf_pq.search(sp, index, queries, k)
+            recalls[kind] = _pq_recall(ids, want)
+            built[kind] = (index, sp, None)
     # matched-recall target: the finest SUB-i8 classic rung present,
     # minus the acceptance band's 0.01. NOT i8's recall — the table
     # entry decides the sub-i8 "auto" slot (dispatch only consults it

@@ -28,3 +28,28 @@ def get_dist_precision():
 def dist_dot(a, b):
     """a @ b with fp32 accumulation at the distance-math precision policy."""
     return jnp.dot(a, b, preferred_element_type=jnp.float32, precision=_precision)
+
+
+def argmin_exact(x, axis: int = -1):
+    """First position of the minimum of ``x`` along ``axis``, exact.
+    ``jnp.argmin`` of f32 compiles for the TPU to a reduce that carries
+    the compared values in bf16: on a v5e it picked the wrong PQ code for
+    ~87% of subvectors. Floats map to int32 keys in the same order (flip
+    the magnitude bits of negatives; -0.0 joins +0.0 first), and an
+    int32 argmin is exact. Every arg-min/max of floats in the library
+    goes through here or :func:`argmax_exact`."""
+    x = jnp.asarray(x)
+    if not jnp.issubdtype(x.dtype, jnp.floating):
+        return jnp.argmin(x, axis=axis).astype(jnp.int32)
+    x = x.astype(jnp.float32)
+    i = jax.lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x), jnp.int32)
+    key = i ^ ((i >> 31) & jnp.int32(0x7FFFFFFF))
+    return jnp.argmin(key, axis=axis).astype(jnp.int32)
+
+
+def argmax_exact(x, axis: int = -1):
+    """First position of the maximum along ``axis`` (see argmin_exact)."""
+    x = jnp.asarray(x)
+    if not jnp.issubdtype(x.dtype, jnp.floating):
+        return jnp.argmax(x, axis=axis).astype(jnp.int32)
+    return argmin_exact(-x, axis)

@@ -290,15 +290,4 @@ def main():
 
 
 if __name__ == "__main__":
-    from raft_tpu.core.exit_guard import guarded_exit
-
-    try:
-        rc = main()
-    except SystemExit as e:
-        rc = e.code if isinstance(e.code, int) else (0 if e.code is None else 1)
-    except BaseException:  # noqa: BLE001
-        import traceback
-
-        traceback.print_exc()
-        rc = 1
-    guarded_exit(rc)
+    sys.exit(main())

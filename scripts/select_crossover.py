@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Measure the select_k dispatch crossover: hardware lax.top_k vs the
 tournament network (VERDICT r4 #4: >= 2x at n=256k, k in {1024, 4096}).
-Emits the crossover table for BASELINE.md.
+Emits the select_k crossover table.
 
 Run: python scripts/select_crossover.py
 """

@@ -1680,6 +1680,4 @@ def _run_chaos_curve(args, ks, dataset, rng, obs, serve) -> int:
 
 
 if __name__ == "__main__":
-    from raft_tpu.core.exit_guard import guarded_exit
-
-    guarded_exit(main())
+    sys.exit(main())

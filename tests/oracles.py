@@ -112,3 +112,40 @@ def eval_neighbours(found_idx, true_idx, found_dist, true_dist, eps: float = 1e-
             if found_idx[i, j] in true_set or found_dist[i, j] <= kth + eps:
                 hits += 1
     return hits / (n * k)
+
+
+def exact_knn_blocked(queries, base, k: int, metric: str = "sqeuclidean",
+                      block: int = 1 << 18):
+    """Exact KNN oracle for large ``base``: float64, in row blocks, so a
+    1M–40M-row dataset never needs the [m, n, d] tensor of
+    :func:`naive_pairwise`. ``base`` is anything that slices to an array
+    (numpy, memmap, or a device array, copied block by block).
+    ``metric`` is "sqeuclidean" or "inner_product". Returns
+    (dist [m,k] float64, idx [m,k] int64), best first. Each block keeps
+    its k best by partition, so an exact tie at a block's k-th place may
+    keep either id."""
+    q = np.asarray(queries, np.float64)
+    qn = (q * q).sum(1)[:, None]
+    best_d = np.full((q.shape[0], 0), np.inf)
+    best_i = np.zeros((q.shape[0], 0), np.int64)
+    for a in range(0, base.shape[0], block):
+        x = np.asarray(base[a:a + block], np.float64)
+        dots = q @ x.T
+        if metric == "inner_product":
+            d = -dots
+        elif metric == "sqeuclidean":
+            d = qn + (x * x).sum(1)[None, :] - 2.0 * dots
+        else:
+            raise ValueError(metric)
+        kk = min(k, d.shape[1])
+        part = np.sort(np.argpartition(d, kk - 1, axis=1)[:, :kk], axis=1)
+        cd = np.concatenate([best_d, np.take_along_axis(d, part, 1)], 1)
+        ci = np.concatenate([best_i, part + a], axis=1)
+        # ids ascend left to right, so a stable sort keeps the lower id
+        # first among equal distances
+        o = np.argsort(cd, axis=1, kind="stable")[:, :k]
+        best_d = np.take_along_axis(cd, o, axis=1)
+        best_i = np.take_along_axis(ci, o, axis=1)
+    if metric == "inner_product":
+        best_d = -best_d
+    return best_d, best_i

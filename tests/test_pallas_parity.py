@@ -58,11 +58,39 @@ def _l2_oracle(q, x, k):
 
 @pytest.mark.parametrize("k", [1, 10, 100])
 def test_fused_topk_exact_bitwise_ids(rng, k):
+    """The exact arm's contract: its j-th id carries the oracle's j-th
+    distance. Ids may differ from the oracle's only between candidates
+    whose oracle distances lie within f32 rounding of each other: the
+    kernel's tile matmul sums in another order than the oracle's whole
+    matmul, so its distances differ by an ulp or two (at k=100 on this
+    data, two ids 2e-6 apart swap places). Exact ties go to the lower id
+    (test_fused_topk_exact_tie_break_lower_id)."""
     q, x = _bf_data(rng)
+    dist = np.asarray(_l2_dist_xla(q, x))
     want = _l2_oracle(q, x, k)
     od, oi = fused_topk(jnp.asarray(q), jnp.asarray(x), k, metric_kind=L2,
                         variant="exact", interpret=True)
-    np.testing.assert_array_equal(np.asarray(oi), want)
+    oi = np.asarray(oi)
+    for row in oi:
+        assert len(set(row.tolist())) == k
+    got_d = np.take_along_axis(dist, oi, axis=1)
+    want_d = np.take_along_axis(dist, want, axis=1)
+    np.testing.assert_allclose(got_d, want_d, rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(od), want_d, rtol=1e-6)
+
+
+@pytest.mark.parametrize("k", [4, 12])
+def test_fused_topk_exact_tie_break_lower_id(k):
+    """Exact distance ties resolve to the lower id, within a row tile and
+    across tiles: every dataset row appears three times, in tiles of 512
+    rows, so each true neighbour comes with two equal copies."""
+    r = np.random.default_rng(7)
+    base = r.standard_normal((300, 16)).astype(np.float32)
+    x = np.concatenate([base, base, base])          # ids i, i+300, i+600
+    q = r.standard_normal((16, 16)).astype(np.float32)
+    od, oi = fused_topk(jnp.asarray(q), jnp.asarray(x), k, metric_kind=L2,
+                        variant="exact", tile_n=512, interpret=True)
+    np.testing.assert_array_equal(np.asarray(oi), _l2_oracle(q, x, k))
 
 
 @pytest.mark.parametrize("k", [10, 200])

@@ -602,10 +602,17 @@ def test_shadow_sampling_live_zero_retraces(data):
         assert srv.stats()["quality"]["estimate"] == 1.0
 
         before = serve.trace_cache_sizes()
-        scored = st["samples"]
+        # the window caps stats()["samples"] at quality_window (8), so
+        # wait on the cumulative counter for 4 more scored samples
+        total0 = _value(obs.snapshot(), "serve.shadow_samples_total",
+                        index="default")
         for j in range(6):
             srv.search(q[6 + j], 4)
-        _wait_samples(mon, scored + 4)
+        deadline = time.monotonic() + 60.0
+        while _value(obs.snapshot(), "serve.shadow_samples_total",
+                     index="default") < total0 + 4:
+            assert time.monotonic() < deadline, mon.stats()
+            time.sleep(0.05)
         # the oracle re-runs ride warmed (bucket, k) programs only
         assert serve.trace_cache_sizes() == before
 
