@@ -10,7 +10,6 @@ from raft_tpu.core.resources import Resources, DeviceResources
 from raft_tpu.core.bitset import Bitset
 from raft_tpu.core.serialize import save_npy, load_npy, serialize_mdspan, deserialize_mdspan
 from raft_tpu.core.logger import logger, set_level
-from raft_tpu.core.trace import annotate, push_range, pop_range
 from raft_tpu.core.interruptible import Interruptible, synchronize
 from raft_tpu.core.device_ndarray import auto_convert_output, cai_wrapper, device_ndarray
 from raft_tpu.core.pipeline import Prefetcher, overlap, resolve_depth
@@ -28,9 +27,6 @@ __all__ = [
     "deserialize_mdspan",
     "logger",
     "set_level",
-    "annotate",
-    "push_range",
-    "pop_range",
     "Interruptible",
     "synchronize",
     "Prefetcher",

@@ -94,16 +94,16 @@ def filter_keep(filter_bits, filter_nbits: int, sample_ids,
     kernels. ``out_of_range`` (static) decides ids ``>= filter_nbits``:
     ``"drop"`` rejects them (allow-list semantics), ``"keep"`` accepts
     them (tombstone semantics over an extended index — new rows were
-    never deleted). Negative ids are always rejected."""
-    import jax.numpy as _jnp
-
-    safe = _jnp.clip(sample_ids, 0, filter_nbits - 1)
-    tested = Bitset.test_bits(filter_bits, safe)
-    in_range = sample_ids < filter_nbits
-    if out_of_range == "keep":
-        tested = tested | ~in_range
-        return tested & (sample_ids >= 0)
-    return tested & in_range & (sample_ids >= 0)
+    never deleted). Negative ids are always rejected. Its device ops run
+    under the named scope ``filter.keep_mask``."""
+    with jax.named_scope("filter.keep_mask"):
+        safe = jnp.clip(sample_ids, 0, filter_nbits - 1)
+        tested = Bitset.test_bits(filter_bits, safe)
+        in_range = sample_ids < filter_nbits
+        if out_of_range == "keep":
+            tested = tested | ~in_range
+            return tested & (sample_ids >= 0)
+        return tested & in_range & (sample_ids >= 0)
 
 
 def resolve_filter_bits(filt, id_bound):

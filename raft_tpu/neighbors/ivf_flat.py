@@ -555,24 +555,28 @@ def _ivf_search(
     sentinel = sentinel_for(metric, jnp.float32)
 
     # ---- coarse phase: queries x centers GEMM + select n_probes ----------
-    # (reference ivf_flat_search-inl.cuh:90-130)
-    cdot = dist_dot(q32, centers.T)
-    if metric == DistanceType.InnerProduct:
-        coarse = cdot
-    elif metric == DistanceType.CosineExpanded:
-        qn = jnp.linalg.norm(q32, axis=1, keepdims=True)
-        cn = jnp.linalg.norm(centers, axis=1)
-        coarse = 1.0 - cdot / jnp.maximum(qn * cn[None, :], 1e-30)
-    else:
-        qn2 = jnp.sum(q32 * q32, axis=1, keepdims=True)
-        cn2 = jnp.sum(centers * centers, axis=1)
-        coarse = qn2 + cn2[None, :] - 2.0 * cdot
-    _, probes = select_k(coarse, n_probes, select_min=select_min)  # [m, np]
+    # (reference ivf_flat_search-inl.cuh:90-130). Each stage runs under a
+    # named scope, so the device trace names its ops after the stage.
+    with jax.named_scope("ivf.coarse"):
+        cdot = dist_dot(q32, centers.T)
+        if metric == DistanceType.InnerProduct:
+            coarse = cdot
+        elif metric == DistanceType.CosineExpanded:
+            qn = jnp.linalg.norm(q32, axis=1, keepdims=True)
+            cn = jnp.linalg.norm(centers, axis=1)
+            coarse = 1.0 - cdot / jnp.maximum(qn * cn[None, :], 1e-30)
+        else:
+            qn2 = jnp.sum(q32 * q32, axis=1, keepdims=True)
+            cn2 = jnp.sum(centers * centers, axis=1)
+            coarse = qn2 + cn2[None, :] - 2.0 * cdot
+        _, probes = select_k(coarse, n_probes,
+                             select_min=select_min)          # [m, np]
 
     # ---- bucketize (query, probe) pairs by list --------------------------
-    (bucket_list, bucket_q, pair_bucket, pair_pos, order, total, nb_pad) = (
-        bucketize_pairs(probes, m, n_probes, C, group, bucket_batch)
-    )
+    with jax.named_scope("ivf.bucketize"):
+        (bucket_list, bucket_q, pair_bucket, pair_pos, order, total,
+         nb_pad) = bucketize_pairs(probes, m, n_probes, C, group,
+                                   bucket_batch)
 
     # ---- scan list blocks: one MXU matmul per (group x list) -------------
     # per-list top-k cannot exceed the list capacity; the final merge over
@@ -582,56 +586,6 @@ def _ivf_search(
     qlen = jnp.sqrt(qnorm)
 
     mm = jnp.bfloat16 if compute_dtype == "bf16" else jnp.float32
-
-    if scan_impl.startswith("pallas"):
-        # fused Pallas kernel: list blocks DMA'd by scalar-prefetch index,
-        # distances + top-k stay in VMEM (raft_tpu.ops.ivf_scan); k <= 256
-        # per list (the R-deep binned extraction's capacity — the
-        # reference's fused path similarly caps at kMaxCapacity=256,
-        # ivf_pq_search.cuh:439 manage_local_topk)
-        from raft_tpu.ops import ivf_scan
-
-        kl = min(kl, 256)
-        qsafe_b = jnp.maximum(bucket_q, 0)
-        qv = q32[qsafe_b].astype(mm)                         # [nb, G, d]
-        if metric == DistanceType.InnerProduct:
-            mk, qaux, pn2 = ivf_scan.IP, None, None
-        elif metric == DistanceType.CosineExpanded:
-            mk, qaux = ivf_scan.COSINE, qlen[qsafe_b]
-            pn2 = (data_norms if data_norms is not None
-                   else jnp.sum(storage.astype(jnp.float32) ** 2, axis=2))
-        else:
-            mk, qaux = ivf_scan.L2, qnorm[qsafe_b]
-            pn2 = (data_norms if data_norms is not None
-                   else jnp.sum(storage.astype(jnp.float32) ** 2, axis=2))
-        keep = None
-        if filter_bits is not None:
-            keep = filter_keep(filter_bits, filter_nbits, indices).astype(
-                jnp.int32
-            )
-        out_d, cand_i = ivf_scan.fused_list_scan_topk(
-            storage, indices, list_sizes, bucket_list, qv, qaux, pn2, keep,
-            k=kl, metric_kind=mk, approx=local_recall_target < 1.0,
-            recall_target=float(local_recall_target),
-            interpret=scan_impl == "pallas_interpret",
-        )                                                    # ids in-kernel
-        if metric == DistanceType.InnerProduct:
-            cand_d = -out_d                                  # min-space -> score
-        else:
-            cand_d = out_d
-        cand_d = jnp.where(jnp.isinf(out_d), sentinel, cand_d)
-        # candidate width comes off the kernel's output: the fold
-        # extraction arm emits its R*128 lane-stack buffer instead of kl
-        out_d, out_i = unbucketize_merge(
-            cand_d, cand_i, pair_bucket, pair_pos, order, total, m,
-            n_probes, int(cand_d.shape[2]), k, select_min, sentinel,
-            approx=merge_recall_target < 1.0,
-            recall_target=merge_recall_target,
-        )
-        out_i = jnp.where(out_d == sentinel, -1, out_i)
-        if metric == DistanceType.L2SqrtExpanded:
-            out_d = jnp.sqrt(jnp.maximum(out_d, 0.0))
-        return out_d, out_i
 
     def body(_, inp):
         bl, bq = inp  # [bb], [bb, group]
@@ -674,27 +628,69 @@ def _ivf_search(
         return None, (ld.reshape(ld.shape[0], -1),
                       lsel.reshape(lsel.shape[0], -1))
 
-    xs = (
-        bucket_list.reshape(-1, bucket_batch),
-        bucket_q.reshape(-1, bucket_batch, group),
-    )
-    _, (cand_d, cand_i) = jax.lax.scan(body, None, xs)
-    cand_d = cand_d.reshape(nb_pad, group, kl)
-    cand_i = cand_i.reshape(nb_pad, group, kl)
+    with jax.named_scope("ivf.scan"):
+        if scan_impl.startswith("pallas"):
+            # fused Pallas kernel: list blocks DMA'd by scalar-prefetch
+            # index, distances + top-k stay in VMEM (raft_tpu.ops.ivf_scan);
+            # k <= 256 per list (the R-deep binned extraction's capacity —
+            # the reference's fused path similarly caps at
+            # kMaxCapacity=256, ivf_pq_search.cuh:439 manage_local_topk)
+            from raft_tpu.ops import ivf_scan
+
+            kl = min(kl, 256)
+            qsafe_b = jnp.maximum(bucket_q, 0)
+            qv = q32[qsafe_b].astype(mm)                     # [nb, G, d]
+            if metric == DistanceType.InnerProduct:
+                mk, qaux, pn2 = ivf_scan.IP, None, None
+            elif metric == DistanceType.CosineExpanded:
+                mk, qaux = ivf_scan.COSINE, qlen[qsafe_b]
+                pn2 = (data_norms if data_norms is not None else
+                       jnp.sum(storage.astype(jnp.float32) ** 2, axis=2))
+            else:
+                mk, qaux = ivf_scan.L2, qnorm[qsafe_b]
+                pn2 = (data_norms if data_norms is not None else
+                       jnp.sum(storage.astype(jnp.float32) ** 2, axis=2))
+            keep = None
+            if filter_bits is not None:
+                keep = filter_keep(filter_bits, filter_nbits,
+                                   indices).astype(jnp.int32)
+            out_d, cand_i = ivf_scan.fused_list_scan_topk(
+                storage, indices, list_sizes, bucket_list, qv, qaux, pn2,
+                keep, k=kl, metric_kind=mk,
+                approx=local_recall_target < 1.0,
+                recall_target=float(local_recall_target),
+                interpret=scan_impl == "pallas_interpret",
+            )                                                # ids in-kernel
+            if metric == DistanceType.InnerProduct:
+                cand_d = -out_d                      # min-space -> score
+            else:
+                cand_d = out_d
+            cand_d = jnp.where(jnp.isinf(out_d), sentinel, cand_d)
+        else:
+            xs = (
+                bucket_list.reshape(-1, bucket_batch),
+                bucket_q.reshape(-1, bucket_batch, group),
+            )
+            _, (cand_d, cand_i) = jax.lax.scan(body, None, xs)
+            cand_d = cand_d.reshape(nb_pad, group, kl)
+            cand_i = cand_i.reshape(nb_pad, group, kl)
 
     # ---- un-bucketize + final merge (search-inl.cuh:194) -----------------
-    out_d, out_i = unbucketize_merge(
-        cand_d, cand_i, pair_bucket, pair_pos, order, total, m, n_probes,
-        kl, k, select_min, sentinel,
-        approx=merge_recall_target < 1.0,
-        recall_target=merge_recall_target,
-    )
-    # fewer than k valid candidates in the probed lists: report id -1, not
-    # whatever id rode along at sentinel distance (the documented contract;
-    # refine would otherwise resurrect filtered-out points)
-    out_i = jnp.where(out_d == sentinel, -1, out_i)
-    if metric == DistanceType.L2SqrtExpanded:
-        out_d = jnp.sqrt(jnp.maximum(out_d, 0.0))
+    # candidate width comes off the scan's output: the kernel's fold
+    # extraction arm emits its R*128 lane-stack buffer instead of kl
+    with jax.named_scope("ivf.merge"):
+        out_d, out_i = unbucketize_merge(
+            cand_d, cand_i, pair_bucket, pair_pos, order, total, m,
+            n_probes, int(cand_d.shape[2]), k, select_min, sentinel,
+            approx=merge_recall_target < 1.0,
+            recall_target=merge_recall_target,
+        )
+        # fewer than k valid candidates in the probed lists: report id -1,
+        # not whatever id rode along at sentinel distance (the documented
+        # contract; refine would otherwise resurrect filtered-out points)
+        out_i = jnp.where(out_d == sentinel, -1, out_i)
+        if metric == DistanceType.L2SqrtExpanded:
+            out_d = jnp.sqrt(jnp.maximum(out_d, 0.0))
     return out_d, out_i
 
 

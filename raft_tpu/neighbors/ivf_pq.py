@@ -2002,19 +2002,22 @@ def _pq_search(
     m = q32.shape[0]
     sentinel = sentinel_for(metric, jnp.float32)
 
-    # coarse phase (ivf_pq_search.cuh:70 select_clusters)
-    cdot = dist_dot(q32, centers.T)
-    if metric == DistanceType.InnerProduct:
-        coarse = cdot
-    else:
-        qn2 = jnp.sum(q32 * q32, axis=1, keepdims=True)
-        cn2 = jnp.sum(centers * centers, axis=1)
-        coarse = qn2 + cn2[None, :] - 2.0 * cdot
-    _, probes = select_k(coarse, n_probes, select_min=select_min)
+    # coarse phase (ivf_pq_search.cuh:70 select_clusters); the stages
+    # run under the same named scopes as ivf_flat._ivf_search's
+    with jax.named_scope("ivf.coarse"):
+        cdot = dist_dot(q32, centers.T)
+        if metric == DistanceType.InnerProduct:
+            coarse = cdot
+        else:
+            qn2 = jnp.sum(q32 * q32, axis=1, keepdims=True)
+            cn2 = jnp.sum(centers * centers, axis=1)
+            coarse = qn2 + cn2[None, :] - 2.0 * cdot
+        _, probes = select_k(coarse, n_probes, select_min=select_min)
 
-    (bucket_list, bucket_q, pair_bucket, pair_pos, order, total, nb_pad) = (
-        bucketize_pairs(probes, m, n_probes, C, group, bucket_batch)
-    )
+    with jax.named_scope("ivf.bucketize"):
+        (bucket_list, bucket_q, pair_bucket, pair_pos, order, total,
+         nb_pad) = bucketize_pairs(probes, m, n_probes, C, group,
+                                   bucket_batch)
 
     kl = min(k, cap)
     q_rot = dist_dot(q32, rotation.T)  # [m, rot_dim]
@@ -2024,95 +2027,6 @@ def _pq_search(
     if lut_dtype == "bf16" and mm is jnp.float32:
         mm = jnp.bfloat16
     decode_via_f8 = lut_dtype == "f8"
-
-    if scan_impl.startswith("pallas"):
-        # fused Pallas scan over the int8 decoded-residual cache: identical
-        # machinery to ivf_flat's kernel — the PQ twist is that the scanned
-        # space is the rotated residual space, so the per-bucket "queries"
-        # are query residuals vs the probed list's center, with the int8
-        # dequant scale folded into them (dots then equal q_res . recon)
-        from raft_tpu.ops import ivf_scan
-
-        kl = min(kl, 256)  # in-kernel extraction budget (see ivf_flat)
-        qsafe_b = jnp.maximum(bucket_q, 0)
-        q_res = q_rot[qsafe_b] - centers_rot[bucket_list][:, None, :]
-        # dequant scaling folds into the query side so the kernel scores
-        # raw cached integers: scalar recon_scale for int8, the per-LIST
-        # per-component scale rows for packed int4 (qv is per-bucket and a
-        # bucket is one list — free per-list granularity). The pq4 code
-        # scan is scale-free (the codebook lives in the kernel's LUT
-        # weights), so qv stays the raw residual.
-        qscale = (cache_scales[bucket_list][:, None, :]
-                  if cache_scales is not None       # per-list (raw caches)
-                  else 1.0 if cache_kind in ("pq4", "rabitq")
-                  else recon_scale)
-        qv = (q_res * qscale).astype(mm)                     # [nb, G, rot]
-        ip = metric == DistanceType.InnerProduct
-        if ip:
-            # dist contribution = -(q_rot . recon); the per-(query, list)
-            # constant q_rot . c_l is added back after the kernel
-            qv = (q_rot[qsafe_b] * qscale).astype(mm)
-            mk, qaux = ivf_scan.IP, None
-        else:
-            mk, qaux = ivf_scan.L2, jnp.sum(q_res * q_res, axis=2)
-        if cache_rabitq:
-            # zero-pad queries to the sign-word width: pad bits decode
-            # -1 in-kernel, so a zero query component nulls them; the
-            # per-row fac scale rides as the kernel's row_scale operand
-            # and norms hold the TRUE residual norms (the estimator's
-            # correct norm term — not the reconstruction's)
-            dpad = recon_cache.shape[1] * 32 - rot_dim
-            if dpad:
-                qv = jnp.pad(qv, ((0, 0), (0, 0), (0, dpad)))
-        keep = None
-        if filter_bits is not None:
-            keep = filter_keep(filter_bits, filter_nbits, indices).astype(
-                jnp.int32
-            )
-        lut_w = None
-        if cache_kind == "pq4":
-            # block-diagonal codebook weights W[v][s*pl + l, s] =
-            # pq_centers[s, v, l]: one [rot, p] matmul per code value
-            # turns the per-subspace LUT build into MXU work (PER_SUBSPACE
-            # only — a per-list codebook would need C of these)
-            p_, K_, pl_ = pq_centers.shape
-            eye = jnp.eye(p_, dtype=jnp.float32)
-            lut_w = (pq_centers.transpose(1, 0, 2)[:, :, :, None]
-                     * eye[None, :, None, :]).reshape(K_, p_ * pl_, p_)
-        norms = rec_norms if cache_qnorms is None else cache_qnorms
-        out_d, cand_i = ivf_scan.fused_list_scan_topk(
-            recon_cache, indices, list_sizes, bucket_list, qv, qaux,
-            None if ip else norms,       # IP kernel never reads norms
-            keep,
-            lut_weights=lut_w,
-            row_scale=cache_fac if cache_rabitq else None,
-            k=kl, metric_kind=mk, approx=local_recall_target < 1.0,
-            recall_target=float(local_recall_target),
-            interpret=scan_impl == "pallas_interpret",
-            packed_i4=cache_i4,
-            packed_bits=cache_rabitq,
-        )                                                    # ids in-kernel
-        if ip:
-            qc = jnp.einsum(
-                "bgd,bd->bg", q_rot[qsafe_b], centers_rot[bucket_list],
-                preferred_element_type=jnp.float32,
-                precision=jax.lax.Precision.HIGHEST,
-            )
-            cand_d = qc[:, :, None] + (-out_d)               # min-space -> score
-        else:
-            cand_d = out_d
-        cand_d = jnp.where(jnp.isinf(out_d), sentinel, cand_d)
-        # candidate width off the kernel output (fold arm emits R*128)
-        out_d, out_i = unbucketize_merge(
-            cand_d, cand_i, pair_bucket, pair_pos, order, total, m,
-            n_probes, int(cand_d.shape[2]), k, select_min, sentinel,
-            approx=merge_recall_target < 1.0,
-            recall_target=merge_recall_target,
-        )
-        out_i = jnp.where(out_d == sentinel, -1, out_i)
-        if metric == DistanceType.L2SqrtExpanded:
-            out_d = jnp.sqrt(jnp.maximum(out_d, 0.0))
-        return out_d, out_i
 
     def body(_, inp):
         bl, bq = inp  # [bb], [bb, group]
@@ -2215,24 +2129,108 @@ def _pq_search(
         bb = ld.shape[0]
         return None, (ld.reshape(bb, -1), li.reshape(bb, -1))
 
-    xs = (
-        bucket_list.reshape(-1, bucket_batch),
-        bucket_q.reshape(-1, bucket_batch, group),
-    )
-    _, (cand_d, cand_i) = jax.lax.scan(body, None, xs)
-    out_d, out_i = unbucketize_merge(
-        cand_d.reshape(nb_pad, group, kl),
-        cand_i.reshape(nb_pad, group, kl),
-        pair_bucket, pair_pos, order, total, m, n_probes, kl, k,
-        select_min, sentinel,
-        approx=merge_recall_target < 1.0,
-        recall_target=merge_recall_target,
-    )
-    # fewer than k valid candidates: id must be -1 (documented contract);
-    # otherwise refine re-scores filtered-out ids back into the top-k
-    out_i = jnp.where(out_d == sentinel, -1, out_i)
-    if metric == DistanceType.L2SqrtExpanded:
-        out_d = jnp.sqrt(jnp.maximum(out_d, 0.0))
+    with jax.named_scope("ivf.scan"):
+        if scan_impl.startswith("pallas"):
+            # fused Pallas scan over the int8 decoded-residual cache: identical
+            # machinery to ivf_flat's kernel — the PQ twist is that the scanned
+            # space is the rotated residual space, so the per-bucket "queries"
+            # are query residuals vs the probed list's center, with the int8
+            # dequant scale folded into them (dots then equal q_res . recon)
+            from raft_tpu.ops import ivf_scan
+
+            kl = min(kl, 256)  # in-kernel extraction budget (see ivf_flat)
+            qsafe_b = jnp.maximum(bucket_q, 0)
+            q_res = q_rot[qsafe_b] - centers_rot[bucket_list][:, None, :]
+            # dequant scaling folds into the query side so the kernel scores
+            # raw cached integers: scalar recon_scale for int8, the per-LIST
+            # per-component scale rows for packed int4 (qv is per-bucket and a
+            # bucket is one list — free per-list granularity). The pq4 code
+            # scan is scale-free (the codebook lives in the kernel's LUT
+            # weights), so qv stays the raw residual.
+            qscale = (cache_scales[bucket_list][:, None, :]
+                      if cache_scales is not None       # per-list (raw caches)
+                      else 1.0 if cache_kind in ("pq4", "rabitq")
+                      else recon_scale)
+            qv = (q_res * qscale).astype(mm)                     # [nb, G, rot]
+            ip = metric == DistanceType.InnerProduct
+            if ip:
+                # dist contribution = -(q_rot . recon); the per-(query, list)
+                # constant q_rot . c_l is added back after the kernel
+                qv = (q_rot[qsafe_b] * qscale).astype(mm)
+                mk, qaux = ivf_scan.IP, None
+            else:
+                mk, qaux = ivf_scan.L2, jnp.sum(q_res * q_res, axis=2)
+            if cache_rabitq:
+                # zero-pad queries to the sign-word width: pad bits decode
+                # -1 in-kernel, so a zero query component nulls them; the
+                # per-row fac scale rides as the kernel's row_scale operand
+                # and norms hold the TRUE residual norms (the estimator's
+                # correct norm term — not the reconstruction's)
+                dpad = recon_cache.shape[1] * 32 - rot_dim
+                if dpad:
+                    qv = jnp.pad(qv, ((0, 0), (0, 0), (0, dpad)))
+            keep = None
+            if filter_bits is not None:
+                keep = filter_keep(filter_bits, filter_nbits, indices).astype(
+                    jnp.int32
+                )
+            lut_w = None
+            if cache_kind == "pq4":
+                # block-diagonal codebook weights W[v][s*pl + l, s] =
+                # pq_centers[s, v, l]: one [rot, p] matmul per code value
+                # turns the per-subspace LUT build into MXU work (PER_SUBSPACE
+                # only — a per-list codebook would need C of these)
+                p_, K_, pl_ = pq_centers.shape
+                eye = jnp.eye(p_, dtype=jnp.float32)
+                lut_w = (pq_centers.transpose(1, 0, 2)[:, :, :, None]
+                         * eye[None, :, None, :]).reshape(K_, p_ * pl_, p_)
+            norms = rec_norms if cache_qnorms is None else cache_qnorms
+            out_d, cand_i = ivf_scan.fused_list_scan_topk(
+                recon_cache, indices, list_sizes, bucket_list, qv, qaux,
+                None if ip else norms,       # IP kernel never reads norms
+                keep,
+                lut_weights=lut_w,
+                row_scale=cache_fac if cache_rabitq else None,
+                k=kl, metric_kind=mk, approx=local_recall_target < 1.0,
+                recall_target=float(local_recall_target),
+                interpret=scan_impl == "pallas_interpret",
+                packed_i4=cache_i4,
+                packed_bits=cache_rabitq,
+            )                                                # ids in-kernel
+            if ip:
+                qc = jnp.einsum(
+                    "bgd,bd->bg", q_rot[qsafe_b], centers_rot[bucket_list],
+                    preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST,
+                )
+                cand_d = qc[:, :, None] + (-out_d)   # min-space -> score
+            else:
+                cand_d = out_d
+            cand_d = jnp.where(jnp.isinf(out_d), sentinel, cand_d)
+        else:
+            xs = (
+                bucket_list.reshape(-1, bucket_batch),
+                bucket_q.reshape(-1, bucket_batch, group),
+            )
+            _, (cand_d, cand_i) = jax.lax.scan(body, None, xs)
+            cand_d = cand_d.reshape(nb_pad, group, kl)
+            cand_i = cand_i.reshape(nb_pad, group, kl)
+
+    # candidate width off the scan's output (the kernel's fold arm
+    # emits R*128)
+    with jax.named_scope("ivf.merge"):
+        out_d, out_i = unbucketize_merge(
+            cand_d, cand_i, pair_bucket, pair_pos, order, total, m,
+            n_probes, int(cand_d.shape[2]), k, select_min, sentinel,
+            approx=merge_recall_target < 1.0,
+            recall_target=merge_recall_target,
+        )
+        # fewer than k valid candidates: id must be -1 (documented
+        # contract); otherwise refine re-scores filtered-out ids back
+        # into the top-k
+        out_i = jnp.where(out_d == sentinel, -1, out_i)
+        if metric == DistanceType.L2SqrtExpanded:
+            out_d = jnp.sqrt(jnp.maximum(out_d, 0.0))
     return out_d, out_i
 
 
@@ -2379,28 +2377,29 @@ def _refine_slots(queries, slots, k: int, metric_val: int,
     Distances are computed at f32 in rotated space (the rotation is
     orthonormal, so L2/IP are preserved); slots < 0 are invalid.
     Returns (dist [m, k], slots [m, k])."""
-    metric = DistanceType(metric_val)
-    q32 = jnp.asarray(queries).astype(jnp.float32)
-    qrot = dist_dot(q32, rotation.T)                     # [m, rot]
-    valid = slots >= 0
-    safe = jnp.maximum(slots, 0)
-    vec = _decode_slots(safe, recon_cache, cache_scales, centers_rot,
-                        recon_scale)                     # [m, c, rot] f32
-    if metric == DistanceType.InnerProduct:
-        # elementwise mult-sum: XLA fuses it into the gather consumer
-        # (the "md,mcd" einsum form measured 4x slower on v5e, r4)
-        d = jnp.sum(vec * qrot[:, None, :], axis=-1, dtype=jnp.float32)
-    else:
-        diff = qrot[:, None, :] - vec
-        d = jnp.sum(diff * diff, axis=-1, dtype=jnp.float32)
-        if metric == DistanceType.L2SqrtExpanded:
-            d = jnp.sqrt(d)
-    sentinel = sentinel_for(metric, jnp.float32)
-    d = jnp.where(valid, d, sentinel)
-    out_d, out_s = merge_topk(d, slots.astype(jnp.int32), k,
-                              is_min_close(metric))
-    out_s = jnp.where(out_d == sentinel, -1, out_s)
-    return out_d, out_s
+    with jax.named_scope("ivf_pq.rerank"):
+        metric = DistanceType(metric_val)
+        q32 = jnp.asarray(queries).astype(jnp.float32)
+        qrot = dist_dot(q32, rotation.T)                     # [m, rot]
+        valid = slots >= 0
+        safe = jnp.maximum(slots, 0)
+        vec = _decode_slots(safe, recon_cache, cache_scales, centers_rot,
+                            recon_scale)                     # [m, c, rot] f32
+        if metric == DistanceType.InnerProduct:
+            # elementwise mult-sum: XLA fuses it into the gather consumer
+            # (the "md,mcd" einsum form measured 4x slower on v5e, r4)
+            d = jnp.sum(vec * qrot[:, None, :], axis=-1, dtype=jnp.float32)
+        else:
+            diff = qrot[:, None, :] - vec
+            d = jnp.sum(diff * diff, axis=-1, dtype=jnp.float32)
+            if metric == DistanceType.L2SqrtExpanded:
+                d = jnp.sqrt(d)
+        sentinel = sentinel_for(metric, jnp.float32)
+        d = jnp.where(valid, d, sentinel)
+        out_d, out_s = merge_topk(d, slots.astype(jnp.int32), k,
+                                  is_min_close(metric))
+        out_s = jnp.where(out_d == sentinel, -1, out_s)
+        return out_d, out_s
 
 
 def _slot_indices(indices):
@@ -2426,38 +2425,39 @@ def _refine_slots_codes(queries, slots, k: int, metric_val: int,
     candidate, ≤ k·ratio rows per query — FusionANNS's
     move-only-shortlist-bytes shape). Distances are f32 in rotated
     space; slots < 0 are invalid. Returns (dist [m, k], slots [m, k])."""
-    metric = DistanceType(metric_val)
-    q32 = jnp.asarray(queries).astype(jnp.float32)
-    qrot = dist_dot(q32, rotation.T)                     # [m, rot]
-    valid = slots >= 0
-    safe = jnp.maximum(slots, 0)
-    if codes.ndim == 2:                                  # flat streamed
-        C = centers_rot.shape[0]
-        cap = codes.shape[0] // C
-        words = codes[safe]                              # [m, c, nw]
-    else:
-        C, cap, _nw = codes.shape
-        words = codes.reshape(C * cap, -1)[safe]         # [m, c, nw]
-    lst = safe // cap
-    u = unpack_codes(words, pq_dim, pq_bits)             # [m, c, p]
-    if codebook_kind == codebook_gen.PER_SUBSPACE:
-        recon = _decode_gather(u, pq_centers, codebook_kind)
-    else:
-        recon = _decode_gather(u, pq_centers, codebook_kind, lst)
-    vec = centers_rot[lst] + recon                       # [m, c, rot]
-    if metric == DistanceType.InnerProduct:
-        d = jnp.sum(vec * qrot[:, None, :], axis=-1, dtype=jnp.float32)
-    else:
-        diff = qrot[:, None, :] - vec
-        d = jnp.sum(diff * diff, axis=-1, dtype=jnp.float32)
-        if metric == DistanceType.L2SqrtExpanded:
-            d = jnp.sqrt(d)
-    sentinel = sentinel_for(metric, jnp.float32)
-    d = jnp.where(valid, d, sentinel)
-    out_d, out_s = merge_topk(d, slots.astype(jnp.int32), k,
-                              is_min_close(metric))
-    out_s = jnp.where(out_d == sentinel, -1, out_s)
-    return out_d, out_s
+    with jax.named_scope("ivf_pq.rerank"):
+        metric = DistanceType(metric_val)
+        q32 = jnp.asarray(queries).astype(jnp.float32)
+        qrot = dist_dot(q32, rotation.T)                     # [m, rot]
+        valid = slots >= 0
+        safe = jnp.maximum(slots, 0)
+        if codes.ndim == 2:                                  # flat streamed
+            C = centers_rot.shape[0]
+            cap = codes.shape[0] // C
+            words = codes[safe]                              # [m, c, nw]
+        else:
+            C, cap, _nw = codes.shape
+            words = codes.reshape(C * cap, -1)[safe]         # [m, c, nw]
+        lst = safe // cap
+        u = unpack_codes(words, pq_dim, pq_bits)             # [m, c, p]
+        if codebook_kind == codebook_gen.PER_SUBSPACE:
+            recon = _decode_gather(u, pq_centers, codebook_kind)
+        else:
+            recon = _decode_gather(u, pq_centers, codebook_kind, lst)
+        vec = centers_rot[lst] + recon                       # [m, c, rot]
+        if metric == DistanceType.InnerProduct:
+            d = jnp.sum(vec * qrot[:, None, :], axis=-1, dtype=jnp.float32)
+        else:
+            diff = qrot[:, None, :] - vec
+            d = jnp.sum(diff * diff, axis=-1, dtype=jnp.float32)
+            if metric == DistanceType.L2SqrtExpanded:
+                d = jnp.sqrt(d)
+        sentinel = sentinel_for(metric, jnp.float32)
+        d = jnp.where(valid, d, sentinel)
+        out_d, out_s = merge_topk(d, slots.astype(jnp.int32), k,
+                                  is_min_close(metric))
+        out_s = jnp.where(out_d == sentinel, -1, out_s)
+        return out_d, out_s
 
 
 def _slot_prefilter(index: Index, prefilter):

@@ -60,7 +60,6 @@ import numpy as np
 from raft_tpu import obs, tuning
 from raft_tpu.analysis import lockwatch
 from raft_tpu.distance.types import DistanceType, resolve_metric
-from raft_tpu.neighbors.refine import refine as _refine_exact
 from raft_tpu.neighbors.refine import score_gathered as _score_gathered
 from raft_tpu.utils.math import next_pow2
 
@@ -147,9 +146,10 @@ class RerankSource:
 
 class DeviceSource(RerankSource):
     """The pre-tiered fast path: the whole dataset device-resident,
-    rerank is one gather + exact scoring (``neighbors.refine``). Right
-    when the originals fit HBM next to the index — no fetch, no
-    residency policy, nothing to warm beyond ``refine._refine``."""
+    rerank is one gather + exact scoring (``neighbors.refine``'s
+    arithmetic, through :func:`_score_fetched` with the dataset as the
+    block). Right when the originals fit HBM next to the index — no
+    fetch, no residency policy, nothing to warm beyond that program."""
 
     kind = "device"
 
@@ -163,8 +163,10 @@ class DeviceSource(RerankSource):
         self.row_bytes = self.dim * self.dataset.dtype.itemsize
 
     def rerank_info(self, queries, candidates, k, metric):
-        d, i = _refine_exact(self.dataset, queries, candidates,
-                                  int(k), metric)
+        candidates = jnp.asarray(candidates)
+        d, i = _score_fetched(jnp.asarray(queries), self.dataset,
+                              candidates, candidates, int(k),
+                              int(resolve_metric(metric)))
         info = FetchInfo(rung=int(self.dataset.shape[0]))
         return d, i, info
 
@@ -564,13 +566,15 @@ def _score_fetched(queries, block, pos, candidates, k: int,
                    metric_val: int):
     """Exact scoring over the fetched miss block only (no hot cache):
     gather [m, c, d] candidate vectors by block position, then the
-    shared :func:`refine.score_gathered` tail."""
-    metric = DistanceType(metric_val)
-    compute = jnp.promote_types(queries.dtype, jnp.float32)
-    q = queries.astype(compute)
-    safe = jnp.clip(pos, 0, block.shape[0] - 1)
-    cand_vecs = block[safe].astype(compute)
-    return _score_gathered(q, cand_vecs, candidates, k, metric)
+    shared :func:`refine.score_gathered` tail. Runs under the named
+    scope ``ivf_pq.rerank``, as every rerank program does."""
+    with jax.named_scope("ivf_pq.rerank"):
+        metric = DistanceType(metric_val)
+        compute = jnp.promote_types(queries.dtype, jnp.float32)
+        q = queries.astype(compute)
+        safe = jnp.clip(pos, 0, block.shape[0] - 1)
+        cand_vecs = block[safe].astype(compute)
+        return _score_gathered(q, cand_vecs, candidates, k, metric)
 
 
 @functools.partial(jax.jit, static_argnums=(6, 7))
@@ -580,13 +584,14 @@ def _score_fetched_hot(queries, block, hot_block, pos, is_hot,
     the hot HBM block where ``is_hot``, the fetched miss block
     elsewhere. Two gathers + a select keep the cost O(m*c*d) — never
     O(hot_capacity) per batch."""
-    metric = DistanceType(metric_val)
-    compute = jnp.promote_types(queries.dtype, jnp.float32)
-    q = queries.astype(compute)
-    vm = block[jnp.clip(pos, 0, block.shape[0] - 1)]
-    vh = hot_block[jnp.clip(pos, 0, hot_block.shape[0] - 1)]
-    cand_vecs = jnp.where(is_hot[..., None], vh, vm).astype(compute)
-    return _score_gathered(q, cand_vecs, candidates, k, metric)
+    with jax.named_scope("ivf_pq.rerank"):
+        metric = DistanceType(metric_val)
+        compute = jnp.promote_types(queries.dtype, jnp.float32)
+        q = queries.astype(compute)
+        vm = block[jnp.clip(pos, 0, block.shape[0] - 1)]
+        vh = hot_block[jnp.clip(pos, 0, hot_block.shape[0] - 1)]
+        cand_vecs = jnp.where(is_hot[..., None], vh, vm).astype(compute)
+        return _score_gathered(q, cand_vecs, candidates, k, metric)
 
 
 @jax.jit

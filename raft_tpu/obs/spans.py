@@ -3,8 +3,8 @@
 ``span(name, **attrs)`` is a context manager capturing host wall-clock
 (and, via :meth:`Span.sync`, device-complete time) into a PER-THREAD
 span tree; every span also opens a ``jax.profiler.TraceAnnotation`` so
-the same names line up in an XLA/TPU profiler capture — this absorbs
-the old ``core/trace.py`` NVTX-analog ranges (which now delegate here).
+the same names line up in an XLA/TPU profiler capture (the analog of
+the reference's NVTX ranges).
 
 Trace-time semantics: a span opened inside jit-traced Python executes
 host-side AT TRACE TIME only — it measures trace/compile attribution
@@ -90,8 +90,7 @@ class Span:
             import jax
 
             # scalar attrs ride into the profiler trace as annotation
-            # metadata — core/trace.py's annotate(name, **kwargs) keeps
-            # emitting the same TraceAnnotation payload through obs
+            # metadata
             meta = {k: v for k, v in self.attrs.items()
                     if isinstance(v, (str, int, float, bool))}
             self._ta = jax.profiler.TraceAnnotation(self.name, **meta)

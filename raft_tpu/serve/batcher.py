@@ -37,6 +37,7 @@ import numpy as np
 
 from raft_tpu import obs
 from raft_tpu.analysis import lockwatch
+from raft_tpu.obs import config as _obs_config
 from raft_tpu.obs import trace as obs_trace
 from raft_tpu.resilience import errors as _rerrors
 from raft_tpu.utils.math import next_pow2
@@ -157,6 +158,9 @@ class Batch:
     # the head request's formation wait — the linger attribution every
     # member trace's batch stage carries
     linger_ms: float = 0.0
+    # time.monotonic() of the drain: where each member's request wait
+    # ends and the batch's dispatch interval (serve.dispatch_ms) begins
+    t_drain: float = 0.0
     # the probe rung this batch dispatches at (ISSUE 14): None = the
     # non-adaptive/exhaustive path; set by the engine's split-by-rung
     # partition (and by warmup, which forces each ladder rung once)
@@ -234,6 +238,7 @@ class MicroBatcher:
 
     # -- admission ---------------------------------------------------------
 
+    # graft-lint: allow-unspanned-entry Server.submit, the one caller, times admission under its serve.request span
     def submit(self, queries: np.ndarray, k: int,
                prefilter=None, deadline: Optional[float] = None) -> Future:
         """Enqueue ``queries`` ([rows, dim]) at ``k``; returns the Future
@@ -245,58 +250,56 @@ class MicroBatcher:
         would push the queue past ``max_queue_rows`` — bounded queues
         are the backpressure contract: reject at the door, never grow
         an unbounded latency tail."""
-        with obs.span("serve.submit", index=self.name,
-                      rows=int(queries.shape[0]), k=int(k)):
-            req = Request(queries=queries, k=int(k), prefilter=prefilter,
-                          future=Future(), deadline=deadline)
-            # the serving entry mints the trace (ISSUE 13): the id is
-            # minted BEFORE admission so a rejection still completes a
-            # (tiny) waterfall naming why the query died at the door
-            req.trace = obs_trace.start_trace(
-                "serve.submit", index=self.name, rows=req.rows,
-                k=int(k))
-            if req.rows > self.max_batch_rows:
-                obs_trace.finish(req.trace, status="rejected",
-                                 reason="oversized")
-                raise ValueError(
-                    f"request rows={req.rows} exceeds max_batch_rows="
-                    f"{self.max_batch_rows}; split the query block or "
-                    "raise ServeParams.max_batch_rows"
-                )
-            reason = None
-            with self._cond:
-                if self._closed or \
-                        self._pending_rows + req.rows > self.max_queue_rows:
-                    reason = "closed" if self._closed else "queue_full"
-                    pending = self._pending_rows
-                else:
-                    req.t_enqueue = time.monotonic()
-                    (self._qp if req.deadline is not None
-                     else self._q).append(req)
-                    self._pending_rows += req.rows
-                    depth = self._pending_rows
-                    self._cond.notify_all()
-            # bookkeeping OUTSIDE the admission lock: classify() in
-            # flight mode synchronously dumps the 4096-event ring to
-            # disk for the fatal `closed` rejection — doing that under
-            # _cond would stall every concurrent submit and the
-            # dispatcher for the dump's duration
-            if reason is not None:
-                obs.counter("serve.rejects_total", index=self.name,
-                            reason=reason)
-                obs_trace.finish(req.trace, status="rejected",
-                                 reason=reason)
-                exc = Overloaded(
-                    f"serve[{self.name}]: {reason} "
-                    f"(pending={pending} rows, "
-                    f"max_queue_rows={self.max_queue_rows})",
-                    reason=reason,
-                )
-                _rerrors.classify(exc)   # file with errors_total/flight
-                raise exc
-            obs.gauge("serve.queue_depth", depth, index=self.name)
-            obs.counter("serve.requests_total", index=self.name)
-            return req.future
+        req = Request(queries=queries, k=int(k), prefilter=prefilter,
+                      future=Future(), deadline=deadline)
+        # the serving entry mints the trace: the id is
+        # minted BEFORE admission so a rejection still completes a
+        # (tiny) waterfall naming why the query died at the door
+        req.trace = obs_trace.start_trace(
+            "serve.submit", index=self.name, rows=req.rows,
+            k=int(k))
+        if req.rows > self.max_batch_rows:
+            obs_trace.finish(req.trace, status="rejected",
+                             reason="oversized")
+            raise ValueError(
+                f"request rows={req.rows} exceeds max_batch_rows="
+                f"{self.max_batch_rows}; split the query block or "
+                "raise ServeParams.max_batch_rows"
+            )
+        reason = None
+        with self._cond:
+            if self._closed or \
+                    self._pending_rows + req.rows > self.max_queue_rows:
+                reason = "closed" if self._closed else "queue_full"
+                pending = self._pending_rows
+            else:
+                req.t_enqueue = time.monotonic()
+                (self._qp if req.deadline is not None
+                 else self._q).append(req)
+                self._pending_rows += req.rows
+                depth = self._pending_rows
+                self._cond.notify_all()
+        # bookkeeping OUTSIDE the admission lock: classify() in
+        # flight mode synchronously dumps the 4096-event ring to
+        # disk for the fatal `closed` rejection — doing that under
+        # _cond would stall every concurrent submit and the
+        # dispatcher for the dump's duration
+        if reason is not None:
+            obs.counter("serve.rejects_total", index=self.name,
+                        reason=reason)
+            obs_trace.finish(req.trace, status="rejected",
+                             reason=reason)
+            exc = Overloaded(
+                f"serve[{self.name}]: {reason} "
+                f"(pending={pending} rows, "
+                f"max_queue_rows={self.max_queue_rows})",
+                reason=reason,
+            )
+            _rerrors.classify(exc)   # file with errors_total/flight
+            raise exc
+        obs.gauge("serve.queue_depth", depth, index=self.name)
+        obs.counter("serve.requests_total", index=self.name)
+        return req.future
 
     # graft-lint: allow-unspanned-entry shadow lane is off the latency path by contract; its only telemetry is the serve.shadow_* counters
     def submit_shadow(self, req: Request) -> List[Request]:
@@ -452,6 +455,8 @@ class MicroBatcher:
             batch = self._next_batch()
             if batch is None:
                 return
+            if _obs_config.ENABLED and not batch.shadow:
+                self._record_waits(batch)
             try:
                 self._dispatch(batch)
             except BaseException as e:  # noqa: BLE001 — classified by the engine; the loop must survive to fail ONLY this batch
@@ -460,6 +465,17 @@ class MicroBatcher:
                                      error=type(e).__name__)
                     if not r.future.done():
                         r.future.set_exception(e)
+
+    def _record_waits(self, batch: Batch) -> None:
+        """Each member's own enqueue->drain wait, one sample per
+        request, and its trace's queue_wait stage, with the batch seq as
+        the span link tying the traces this batch serves. Runs after the
+        admission lock is released."""
+        for r in batch.requests:
+            wait_ms = (batch.t_drain - r.t_enqueue) * 1e3
+            obs.observe("serve.request_wait_ms", wait_ms, index=self.name)
+            obs_trace.stage(r.trace, "queue_wait", ms=wait_ms,
+                            batch_seq=batch.seq, bucket=batch.bucket)
 
     def _next_batch(self) -> Optional[Batch]:
         with self._cond:
@@ -562,16 +578,9 @@ class MicroBatcher:
         now = time.monotonic()
         linger_ms = (now - head.t_enqueue) * 1e3
         obs.observe("serve.queue_wait_ms", linger_ms, index=self.name)
-        # per-request queue_wait stages: each member trace records ITS
-        # enqueue->drain wait, with the batch seq as the span link tying
-        # the traces this batch serves together
-        for r in taken:
-            obs_trace.stage(r.trace, "queue_wait",
-                            ms=(now - r.t_enqueue) * 1e3,
-                            batch_seq=self._seq, bucket=bucket)
         return Batch(requests=taken, rows=rows, bucket=bucket,
                      prefilter=head.prefilter, seq=self._seq,
-                     linger_ms=linger_ms)
+                     linger_ms=linger_ms, t_drain=now)
 
     def _drain_shadow_locked(self) -> Batch:
         """Drain one filter-homogeneous run off the shadow lane into a

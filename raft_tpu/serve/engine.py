@@ -626,7 +626,7 @@ class _IndexServing:
         for _ in range(8):
             gen = self.registry.pin(self.name)
             st = gen.handle.state
-            # graft-lint: allow-unbalanced-acquire ownership transfer: _dispatch_once's finally releases st.lock with gen
+            # graft-lint: allow-unbalanced-acquire ownership transfer: _enqueue's finally releases st.lock
             st.lock.acquire()
             if self.registry.get(self.name) is gen:
                 return gen, st
@@ -636,7 +636,7 @@ class _IndexServing:
         # state are still a valid pair for a non-compaction swap)
         gen = self.registry.pin(self.name)
         st = gen.handle.state
-        # graft-lint: allow-unbalanced-acquire ownership transfer: _dispatch_once's finally releases st.lock with gen
+        # graft-lint: allow-unbalanced-acquire ownership transfer: _enqueue's finally releases st.lock
         st.lock.acquire()
         return gen, st
 
@@ -796,7 +796,7 @@ class _IndexServing:
             bucket=choose_bucket(self.batcher.ladder, rows,
                                  ceiling=self.batcher.ceiling),
             prefilter=batch.prefilter, seq=batch.seq,
-            linger_ms=batch.linger_ms, rung=rung,
+            linger_ms=batch.linger_ms, t_drain=batch.t_drain, rung=rung,
         )
 
     def _dispatch(self, batch: Batch) -> None:
@@ -959,8 +959,48 @@ class _IndexServing:
     def _dispatch_once(self, batch: Batch,
                        force_sync: bool = False) -> None:
         pipelined = self.pipeline_depth > 0 and not force_sync
-        gen, st = self._pin_consistent()
+        with obs.span("serve.dispatch", index=self.name,
+                      bucket=batch.bucket, rows=batch.rows,
+                      rung=batch.rung, pipelined=pipelined) as sp:
+            gen, h, d, i, t0 = self._enqueue(batch, sp)
+        # a batch's life tiles into its requests' wait (to the drain),
+        # dispatch, device wait and deliver, each recorded at its end
+        t_enq = time.monotonic()
+        if _obs_config.ENABLED:
+            obs.observe("serve.dispatch_ms", (t_enq - batch.t_drain) * 1e3,
+                        buckets=_LAT_BUCKETS, index=self.name,
+                        bucket=str(batch.bucket))
         handed_off = False
+        try:
+            if pipelined:
+                # graft-flow handoff: the ticket owns the pin from here;
+                # the completion thread syncs, records service time, and
+                # delivers while this (batcher) thread pads + uploads +
+                # gathers for the NEXT batch. XLA's async dispatch means
+                # the device is already running this batch.
+                self._pipe_put((batch, gen, h, d, i, t0, t_enq))
+                handed_off = True
+                return
+            t_ready = self._await(batch, d, i, t_enq)
+            latency_ms = (time.perf_counter() - t0) * 1e3
+            # feed the deadline machinery's service estimate (the
+            # batcher's linger slack test and _shed_missed read the
+            # p95, keyed per rung — rungs differ by multiples)
+            self.batcher.note_service_ms(batch.bucket, latency_ms,
+                                         rung=batch.rung)
+            self._deliver(batch, gen, h, d, i, latency_ms, t_ready)
+        finally:
+            if not handed_off:
+                gen.release()
+
+    def _enqueue(self, batch: Batch, sp):
+        """The host half of a dispatch, under the ``serve.dispatch``
+        span ``sp``: pin the generation, snapshot the mutation overlay,
+        build the side buffer, and enqueue the search on the device
+        without waiting for it. Returns ``(gen, handle, d, i, t0)`` with
+        the pin owned by the caller (released here only on failure);
+        ``t0`` is where ``serve.batch_latency_ms`` starts."""
+        gen, st = self._pin_consistent()
         try:
             h: _Handle = gen.handle
             try:
@@ -980,42 +1020,33 @@ class _IndexServing:
                 st.lock.release()
             side_idx, side_ids = h.side_build(side_snap)
             t0 = time.perf_counter()
-            with obs.span("serve.batch", index=self.name,
-                          bucket=batch.bucket, rows=batch.rows,
-                          rung=batch.rung, generation=gen.version,
-                          pipelined=pipelined) as sp:
-                # fault point: where a real device failure would surface.
-                # Deliberately BEFORE the async handoff — injected faults
-                # strike here on the batcher thread, inside
-                # resilience.run, so retry and OOM-ladder semantics are
-                # byte-for-byte those of the synchronous path at any
-                # pipeline depth.
-                faultinject.check(stage="serve.dispatch", chunk=batch.seq)
-                d, i = self._run_search(
-                    h, batch, main_bits, side_bits, side_idx, side_ids)
-                if not pipelined:
-                    jax.block_until_ready((d, i))
-                sp.set(k_pad=int(d.shape[1]))
-            if pipelined:
-                # graft-flow handoff: the ticket owns the pin from here;
-                # the completion thread syncs, records service time, and
-                # delivers while this (batcher) thread pads + uploads +
-                # gathers for the NEXT batch. XLA's async dispatch means
-                # the device is already running this batch.
-                self._pipe_put((batch, gen, h, d, i, t0))
-                handed_off = True
-                return
-            latency_ms = (time.perf_counter() - t0) * 1e3
-            # feed the deadline machinery's service estimate (the
-            # batcher's linger slack test and _shed_missed read the
-            # p95, keyed per rung — rungs differ by multiples)
-            self.batcher.note_service_ms(batch.bucket, latency_ms,
-                                         rung=batch.rung)
-            self._deliver(batch, gen, h, np.asarray(d), np.asarray(i),
-                          latency_ms)
-        finally:
-            if not handed_off:
-                gen.release()
+            sp.set(generation=gen.version)
+            # fault point: where a real device failure would surface.
+            # Deliberately BEFORE the async handoff — injected faults
+            # strike here on the batcher thread, inside resilience.run,
+            # so retry and OOM-ladder semantics are byte-for-byte those
+            # of the synchronous path at any pipeline depth.
+            faultinject.check(stage="serve.dispatch", chunk=batch.seq)
+            d, i = self._run_search(
+                h, batch, main_bits, side_bits, side_idx, side_ids)
+            sp.set(k_pad=int(d.shape[1]))
+        except BaseException:
+            gen.release()
+            raise
+        return gen, h, d, i, t0
+
+    def _await(self, batch: Batch, d, i, t_enq: float) -> float:
+        """Block until the batch's outputs are ready, under the
+        ``serve.await`` span; its device wait (``serve.device_wait_ms``)
+        runs from the enqueue at ``t_enq``. Returns the ready time."""
+        with obs.span("serve.await", index=self.name, bucket=batch.bucket):
+            jax.block_until_ready((d, i))
+        t_ready = time.monotonic()
+        if _obs_config.ENABLED:
+            obs.observe("serve.device_wait_ms", (t_ready - t_enq) * 1e3,
+                        buckets=_LAT_BUCKETS, index=self.name,
+                        bucket=str(batch.bucket))
+        return t_ready
 
     # -- graft-flow completion pipeline (docs/serving.md §12) --------------
 
@@ -1070,10 +1101,10 @@ class _IndexServing:
         mode, so resilience.run's retry budget and the OOM
         split-ladder apply without this thread ever re-entering its own
         queue (the self-deadlock a recursive enqueue would be)."""
-        batch, gen, h, d, i, t0 = ticket
+        batch, gen, h, d, i, t0, t_enq = ticket
         try:
             try:
-                jax.block_until_ready((d, i))
+                t_ready = self._await(batch, d, i, t_enq)
             except BaseException as e:  # noqa: BLE001 — classified below
                 kind = _rerrors.classify(e)
                 if kind in (_rerrors.TRANSIENT, _rerrors.DEAD,
@@ -1092,8 +1123,7 @@ class _IndexServing:
             latency_ms = (time.perf_counter() - t0) * 1e3
             self.batcher.note_service_ms(batch.bucket, latency_ms,
                                          rung=batch.rung)
-            self._deliver(batch, gen, h, np.asarray(d), np.asarray(i),
-                          latency_ms)
+            self._deliver(batch, gen, h, d, i, latency_ms, t_ready)
         except BaseException as e:  # noqa: BLE001 — must not kill the loop
             kind = _rerrors.classify(e)
             for r in batch.requests:
@@ -1147,61 +1177,74 @@ class _IndexServing:
                                     h.select_min)
         return d, i
 
-    def _deliver(self, batch: Batch, gen, h: _Handle,
-                 d: np.ndarray, i: np.ndarray, latency_ms: float) -> None:
-        row = 0
-        ext = h.state.translate_out(i.astype(np.int64)) \
-            if h.state.has_translation else i
-        # a slot at the sentinel distance is a filtered-out (tombstoned)
-        # or padding candidate that survived top-k only because fewer
-        # than k live rows existed: brute_force._search and the side
-        # merge keep such slots' REAL ids (ivf_* map them to -1 in the
-        # kernel), so mask them here rather than hand a deleted row's id
-        # to the client
-        sent = np.inf if h.select_min else -np.inf
-        ext = np.where(d == sent, np.asarray(-1, ext.dtype), ext)
-        now = time.monotonic()
-        for r in batch.requests:
-            rd = d[row:row + r.rows, :r.k]
-            ri = ext[row:row + r.rows, :r.k]
-            row += r.rows
-            r.future.generation = gen.version
-            # remaining SLO budget at delivery: negative = a miss (the
-            # request was served late rather than shed — counted so the
-            # SLO harness can tell the two apart)
-            slack_ms = None
-            if r.deadline is not None:
-                slack_ms = round((r.deadline - now) * 1e3, 3)
-                if slack_ms < 0:
-                    obs.counter("serve.deadline_miss_total",
-                                index=self.name)
-            # the shared device work, attributed to every member trace:
-            # batch_seq is the span LINK (one batch serves many traces),
-            # linger_ms the batching policy's share of the wait; rung /
-            # deadline_slack_ms are the ISSUE-14 waterfall columns
-            # (obs_report.py renders them per stage)
-            obs_trace.stage(r.trace, "batch_search", ms=latency_ms,
-                            bucket=batch.bucket, batch_seq=batch.seq,
-                            linger_ms=round(batch.linger_ms, 3),
-                            rung=batch.rung,
-                            deadline_slack_ms=slack_ms,
-                            generation=gen.version)
-            if r.future.done():
-                obs_trace.finish(r.trace, status="error",
-                                 error="already_done")
-                continue
-            if rd.shape[1] < r.k:
-                # a swap shrank the index below this request's k after
-                # admission: fail loudly, never hand back fewer columns
-                # than asked
-                obs_trace.finish(r.trace, status="failed",
-                                 error="k_exceeds_rows")
-                r.future.set_exception(ValueError(
-                    f"k={r.k} exceeds index rows={h.rows} after swap"))
-            else:
-                obs_trace.finish(r.trace, status="ok",
-                                 generation=gen.version)
-                r.future.set_result((rd, ri))
+    def _deliver(self, batch: Batch, gen, h: _Handle, d, i,
+                 latency_ms: float, t_ready: float) -> None:
+        """Fetch the batch's outputs and resolve each member's future,
+        under the ``serve.deliver`` span; the deliver interval
+        (``serve.deliver_ms``) runs from ``t_ready`` to the last future
+        resolved."""
+        with obs.span("serve.deliver", index=self.name,
+                      bucket=batch.bucket, rows=batch.rows):
+            d = np.asarray(d)
+            i = np.asarray(i)
+            row = 0
+            ext = h.state.translate_out(i.astype(np.int64)) \
+                if h.state.has_translation else i
+            # a slot at the sentinel distance is a filtered-out (tombstoned)
+            # or padding candidate that survived top-k only because fewer
+            # than k live rows existed: brute_force._search and the side
+            # merge keep such slots' REAL ids (ivf_* map them to -1 in the
+            # kernel), so mask them here rather than hand a deleted row's id
+            # to the client
+            sent = np.inf if h.select_min else -np.inf
+            ext = np.where(d == sent, np.asarray(-1, ext.dtype), ext)
+            now = time.monotonic()
+            for r in batch.requests:
+                rd = d[row:row + r.rows, :r.k]
+                ri = ext[row:row + r.rows, :r.k]
+                row += r.rows
+                r.future.generation = gen.version
+                # remaining SLO budget at delivery: negative = a miss (the
+                # request was served late rather than shed — counted so the
+                # SLO harness can tell the two apart)
+                slack_ms = None
+                if r.deadline is not None:
+                    slack_ms = round((r.deadline - now) * 1e3, 3)
+                    if slack_ms < 0:
+                        obs.counter("serve.deadline_miss_total",
+                                    index=self.name)
+                # the shared device work, attributed to every member trace:
+                # batch_seq is the span LINK (one batch serves many traces),
+                # linger_ms the batching policy's share of the wait; rung /
+                # deadline_slack_ms are the waterfall's SLO columns
+                # (obs_report.py renders them per stage)
+                obs_trace.stage(r.trace, "batch_search", ms=latency_ms,
+                                bucket=batch.bucket, batch_seq=batch.seq,
+                                linger_ms=round(batch.linger_ms, 3),
+                                rung=batch.rung,
+                                deadline_slack_ms=slack_ms,
+                                generation=gen.version)
+                if r.future.done():
+                    obs_trace.finish(r.trace, status="error",
+                                     error="already_done")
+                    continue
+                if rd.shape[1] < r.k:
+                    # a swap shrank the index below this request's k after
+                    # admission: fail loudly, never hand back fewer columns
+                    # than asked
+                    obs_trace.finish(r.trace, status="failed",
+                                     error="k_exceeds_rows")
+                    r.future.set_exception(ValueError(
+                        f"k={r.k} exceeds index rows={h.rows} after swap"))
+                else:
+                    obs_trace.finish(r.trace, status="ok",
+                                     generation=gen.version)
+                    r.future.set_result((rd, ri))
+        if _obs_config.ENABLED:
+            obs.observe("serve.deliver_ms",
+                        (time.monotonic() - t_ready) * 1e3,
+                        buckets=_LAT_BUCKETS, index=self.name,
+                        bucket=str(batch.bucket))
         obs.counter("serve.queries_total", batch.rows, index=self.name)
         obs.observe("serve.batch_latency_ms", latency_ms,
                     buckets=_LAT_BUCKETS, index=self.name,

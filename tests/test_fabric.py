@@ -343,7 +343,10 @@ def test_fabric_trace_hedged_race_records_both_attempts(_obs_on):
     the winner marked hedge_win and the loser hedge_loser."""
     obs = _obs_on
     ds, q = _data()
-    with serve.Fabric(ds, params=_params(), group="local") as fab:
+    # the stalled primary answers a second after the hedge fires: the
+    # replica wins however slowly parallel test workers let it answer
+    with serve.Fabric(ds, params=_params(slow_ms=1000.0),
+                      group="local") as fab:
         fab.search(q, 5)
         obs.trace.reset()
         with faultinject.inject("slow@proc:0*1"):

@@ -5,9 +5,10 @@ registry semantics (counter/gauge/histogram bucket edges, label
 keying), Prometheus exposition round-trip, flight-recorder dump on an
 injected ``dead@stage:search`` fault, the resilience/tuning wiring
 (retries, OOM-ladder downshifts, checkpoint counters, dispatch
-counts), the GL007 recompile hook, thread-local legacy trace ranges,
-an off-path overhead guard, and the ISSUE acceptance run (ivf_pq
-build+search under ``oom@chunk`` + sharded coverage)."""
+counts), the GL007 recompile hook, the named device stages of the
+search programs, an off-path overhead guard (with the serve path
+exercised), and the acceptance run (ivf_pq build+search under
+``oom@chunk`` + sharded coverage)."""
 
 import json
 import os
@@ -102,28 +103,42 @@ def test_off_path_is_shared_singleton_and_registry_silent():
 
 
 def test_off_path_retains_no_allocations():
+    from raft_tpu import serve
+
     # warm every code path first so lazy init cannot count as growth
     obs.counter("warm")
     with obs.span("warm"):
         pass
+    x = np.random.default_rng(0).standard_normal((64, 8)).astype(
+        np.float32)
+    srv = serve.Server(serve.ServeParams(max_batch_rows=4, max_k=4))
     obs_dir = os.path.dirname(obs.__file__)
-    tracemalloc.start()
     try:
-        base = tracemalloc.take_snapshot()
-        payload = {"q": 1}
-        for _ in range(500):
-            obs.counter("x", 1, algo="y")
-            obs.observe("h", 1.0, stage="s")
-            with obs.span("s", a=1) as sp:
-                sp.set(b=2)
-            # graft-trace joins the off-path contract (ISSUE 13)
-            obs.start_trace("e", k=4)
-            obs.traced_payload(payload)
-            obs.trace.stage(None, "rpc", ms=1.0)
-            obs.trace.finish(None)
-        after = tracemalloc.take_snapshot()
+        srv.create_index("default", x)
+        srv.submit(x[:1], 2).result(timeout=60)
+        tracemalloc.start()
+        try:
+            base = tracemalloc.take_snapshot()
+            payload = {"q": 1}
+            for i in range(500):
+                obs.counter("x", 1, algo="y")
+                obs.observe("h", 1.0, stage="s")
+                with obs.span("s", a=1) as sp:
+                    sp.set(b=2)
+                # graft-trace joins the off-path contract
+                obs.start_trace("e", k=4)
+                obs.traced_payload(payload)
+                obs.trace.stage(None, "rpc", ms=1.0)
+                obs.trace.finish(None)
+                if i % 25 == 0:
+                    # a served request: admission, then its batch's
+                    # wait, dispatch, device wait and deliver
+                    srv.submit(x[i % 64], 2).result(timeout=60)
+            after = tracemalloc.take_snapshot()
+        finally:
+            tracemalloc.stop()
     finally:
-        tracemalloc.stop()
+        srv.close()
     retained = sum(
         st.size_diff
         for st in after.compare_to(base, "filename")
@@ -365,38 +380,81 @@ def test_entry_span_failure_emits_no_entry_metrics():
     assert _value(snap, "span_ms", name="demo.search") is not None
 
 
-def test_legacy_trace_ranges_are_thread_local():
-    from raft_tpu.core import trace
-
-    trace.push_range("main-range")
-    try:
-        done = threading.Event()
-
-        def worker():
-            trace.push_range("worker-range")
-            trace.pop_range()
-            # a second pop on THIS thread must find an empty local stack,
-            # not main's range (the pre-fix module-global bug)
-            trace.pop_range()
-            done.set()
-
-        t = threading.Thread(target=worker)
-        t.start()
-        t.join(timeout=10)
-        assert done.is_set()
-        assert len(trace._range_stack()) == 1    # main's range survived
-    finally:
-        trace.pop_range()
-    assert trace._range_stack() == []
+# ---------------------------------------------------------------------------
+# named device stages of the search programs
+# ---------------------------------------------------------------------------
 
 
-def test_trace_annotate_feeds_obs_spans():
-    from raft_tpu.core import trace
+def _compiled_text(monkeypatch, module, name, call):
+    """The compiled HLO text of the jitted ``module.<name>`` as ``call()``
+    dispatches it (the last dispatch)."""
+    fn = getattr(module, name)
+    texts = []
 
-    obs.set_mode("on")
-    with trace.annotate("legacy-range"):
-        pass
-    assert obs.recent()[-1][1]["name"] == "legacy-range"
+    def spy(*args, **kwargs):
+        texts.append(fn.lower(*args, **kwargs).compile().as_text())
+        return fn(*args, **kwargs)
+
+    monkeypatch.setattr(module, name, spy)
+    call()
+    assert texts, f"{name} was not dispatched"
+    return texts[-1]
+
+
+_IVF_STAGES = ("ivf.coarse", "ivf.bucketize", "ivf.scan", "ivf.merge")
+
+
+@pytest.mark.parametrize("algo", ["ivf_flat", "ivf_pq"])
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+def test_search_program_names_its_device_stages(monkeypatch, algo, impl):
+    """Each stage of the IVF search programs carries its named scope in
+    the compiled program's op metadata, the keep-mask of a filtered
+    search under the scan's: what a profiler trace names the device ops
+    by."""
+    from raft_tpu.core.bitset import Bitset
+    from raft_tpu.neighbors import ivf_flat, ivf_pq
+
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal((512, 16)).astype(np.float32)
+    q = rng.standard_normal((8, 16)).astype(np.float32)
+    keep = np.ones(512, bool)
+    keep[::7] = False
+    bits = Bitset.from_dense(jnp.asarray(keep))
+    if algo == "ivf_flat":
+        mod, prog = ivf_flat, "_ivf_search"
+        index = ivf_flat.build(ivf_flat.IndexParams(n_lists=8), x)
+        sp = ivf_flat.SearchParams(n_probes=4, scan_impl=impl)
+    else:
+        mod, prog = ivf_pq, "_pq_search"
+        index = ivf_pq.build(ivf_pq.IndexParams(
+            n_lists=8, pq_dim=8, kmeans_n_iters=4), x)
+        sp = ivf_pq.SearchParams(n_probes=4, scan_impl=impl)
+    text = _compiled_text(monkeypatch, mod, prog,
+                          lambda: mod.search(sp, index, q, 4,
+                                             prefilter=bits))
+    for stage in _IVF_STAGES:
+        assert f'op_name="jit({prog})/{stage}/' in text, stage
+    assert f"jit({prog})/ivf.scan/" in text
+    assert re.search(rf'op_name="jit\({prog}\)/ivf\.scan/[^"]*'
+                     r'filter\.keep_mask/', text)
+
+
+def test_rerank_program_names_its_device_stage(monkeypatch):
+    """The exact rerank of ``ivf_pq.search_refined`` over resident rows
+    runs under the ``ivf_pq.rerank`` scope."""
+    from raft_tpu.neighbors import ivf_pq, tiered
+
+    rng = np.random.default_rng(1)
+    x = rng.standard_normal((512, 16)).astype(np.float32)
+    q = rng.standard_normal((8, 16)).astype(np.float32)
+    index = ivf_pq.build(ivf_pq.IndexParams(
+        n_lists=8, pq_dim=8, kmeans_n_iters=4), x)
+    sp = ivf_pq.SearchParams(n_probes=4)
+    text = _compiled_text(
+        monkeypatch, tiered, "_score_fetched",
+        lambda: ivf_pq.search_refined(sp, index, q, 4, refine_ratio=2,
+                                      dataset=jnp.asarray(x)))
+    assert 'op_name="jit(_score_fetched)/ivf_pq.rerank/' in text
 
 
 # ---------------------------------------------------------------------------

@@ -877,6 +877,119 @@ def test_serve_trace_waterfalls_share_batch_span_link(data):
         obs.reset()
 
 
+# ---------------------------------------------------------------------------
+# a served batch's life: request wait, dispatch, device wait, deliver
+# ---------------------------------------------------------------------------
+
+_BATCH_INTERVALS = ("serve.dispatch_ms", "serve.device_wait_ms",
+                    "serve.deliver_ms")
+
+
+def _hist(snap, name):
+    """(count, sum) of the histogram ``name`` over all its label sets."""
+    pts = snap["metrics"].get(name, {}).get("points", [])
+    return sum(p["count"] for p in pts), sum(p["sum"] for p in pts)
+
+
+def _host_spans(logdir, names):
+    """{name: [(start_ns, end_ns), ...]} of the profiler trace's host
+    events named in ``names``, each list in start order."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
+    out = {n: [] for n in names}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in out:
+                    out[ev.name].append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    return {n: sorted(v) for n, v in out.items()}
+
+
+@pytest.mark.parametrize("depth", [2, 0], ids=["pipelined", "sync"])
+def test_serve_batch_intervals_tile_each_request(data, depth, tmp_path):
+    """With obs on, every request records its own wait, and every batch
+    its dispatch, device wait and deliver: one sample each, as spans in
+    the profiler trace in that order, without overlap. Requests served
+    one at a time spend at least the four intervals' sum in the
+    server."""
+    import jax
+
+    from raft_tpu import obs
+
+    dataset, queries = data
+    obs.set_mode("on")
+    try:
+        with serve.Server(_params(max_wait_ms=2.0,
+                                  pipeline_depth=depth)) as srv:
+            srv.create_index("default", dataset, algo="ivf_flat",
+                             build_params=ivf_flat.IndexParams(n_lists=8))
+            obs.reset()
+            waited = 0.0
+            for row in queries[:6]:
+                t0 = time.monotonic()
+                srv.submit(row[None], 4).result(timeout=60)
+                waited += time.monotonic() - t0
+            snap = obs.snapshot(runtime_gauges=False)
+            parts = sum(_hist(snap, n)[1] for n in
+                        ("serve.request_wait_ms",) + _BATCH_INTERVALS)
+            assert 0 < parts <= waited * 1e3
+
+            obs.reset()
+            with jax.profiler.trace(str(tmp_path)):
+                futs = [srv.submit(row[None], 4) for row in queries]
+                for f in futs:
+                    f.result(timeout=60)
+            snap = obs.snapshot(runtime_gauges=False)
+    finally:
+        obs.set_mode(None)
+        obs.reset()
+    batches = sum(p["value"] for p in
+                  snap["metrics"]["serve.batches_total"]["points"])
+    assert 0 < batches <= len(queries)
+    assert _hist(snap, "serve.request_wait_ms")[0] == len(queries)
+    for name in _BATCH_INTERVALS:
+        assert _hist(snap, name)[0] == batches, name
+    spans = _host_spans(tmp_path, ("serve.dispatch", "serve.await",
+                                   "serve.deliver"))
+    assert all(len(v) == batches for v in spans.values()), spans
+    for disp, wait, dlv in zip(spans["serve.dispatch"],
+                               spans["serve.await"],
+                               spans["serve.deliver"]):
+        assert disp[1] <= wait[0] and wait[1] <= dlv[0]
+    # each interval holds its span (within clock-read slack per batch)
+    for hist, span in (("serve.dispatch_ms", "serve.dispatch"),
+                       ("serve.device_wait_ms", "serve.await"),
+                       ("serve.deliver_ms", "serve.deliver")):
+        held_ms = sum(e - s for s, e in spans[span]) / 1e6
+        assert _hist(snap, hist)[1] >= held_ms - 0.05 * batches, hist
+
+
+def test_serve_batch_intervals_silent_with_obs_off(data):
+    from raft_tpu import obs
+
+    dataset, queries = data
+    obs.reset()
+    with serve.Server(_params(pipeline_depth=2)) as srv:
+        srv.create_index("default", dataset, algo="ivf_flat",
+                         build_params=ivf_flat.IndexParams(n_lists=8))
+        futs = [srv.submit(row[None], 4) for row in queries]
+        for f in futs:
+            f.result(timeout=60)
+    obs.set_mode("on")
+    try:
+        metrics = obs.snapshot(runtime_gauges=False)["metrics"]
+    finally:
+        obs.set_mode(None)
+    for name in ("serve.request_wait_ms",) + _BATCH_INTERVALS:
+        assert name not in metrics
+
+
 def test_threadsan_suite_verdict_zzz():
     """Suite-level ISSUE-7 acceptance (runs last in file order): every
     serve test above constructed its locks through the sanitizer, and

@@ -93,6 +93,38 @@ def test_ivf_scan_compiles(chip, extract):
              ((C, cap), F32))
 
 
+def test_ivf_search_keeps_scan_kernel_name_and_stage_scopes(chip):
+    """The served IVF-Flat program at the serve cell's shapes (SIFT-1M
+    lists of capacity 1408, a 256-query bucket, nprobe 64, k=10, the
+    tombstone keep-mask): the scan is still an instruction named after
+    ``_fused_list_scan_topk``, the name benchmark/metrics/kernels.json
+    matches in a trace, and each stage carries its named scope."""
+    import re
+
+    ivf_flat = importlib.import_module("raft_tpu.neighbors.ivf_flat")
+    from raft_tpu.distance.types import DistanceType
+
+    m, C, cap, d, k, n_probes, n = 256, 1024, 1408, 128, 10, 64, 1_000_000
+    group = ivf_flat.adaptive_query_group(m, n_probes, C, 256)
+
+    def run(q, centers, storage, ids, sizes, norms, bits):
+        return ivf_flat._ivf_search(
+            q, centers, storage, ids, sizes, k, n_probes,
+            int(DistanceType.L2Expanded), group, 32, n, "bf16", 0.95, 1.0,
+            norms, bits, scan_impl="pallas")
+
+    shapes = [((m, d), F32), ((C, d), F32), ((C, cap, d), F32),
+              ((C, cap), I32), ((C,), I32), ((C, cap), F32),
+              ((-(-n // 32),), jnp.uint32)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in shapes]
+    text = jax.jit(run).lower(*args).compile().as_text()
+    assert re.search(r"^\s*%?[\w.]*_fused_list_scan_topk[\w.]* = ", text,
+                     re.M)
+    for scope in ("ivf.coarse", "ivf.bucketize", "ivf.scan", "ivf.merge",
+                  "ivf.scan/filter.keep_mask"):
+        assert f"jit(_ivf_search)/{scope}/" in text, scope
+
+
 @pytest.mark.parametrize("cache", ["i8", "i4"])
 def test_ivf_scan_pq_cache_compiles(chip, cache):
     """IVF-PQ DEEP-10M list shapes: 1024 lists, capacity 12288, rot 96,
