@@ -263,7 +263,7 @@ def sharded_ivf_search(
         # graft-lint: allow-hand-wired-pipeline deliberate single-stage fast path: one collective per-shard scan + merge, no multi-stage tail
         d, i = ivf_flat._ivf_search(
             q, centers, storage, indices, list_sizes,
-            int(k), n_probes, metric, group, bucket_batch, 0,
+            int(k), n_probes, metric, group, bucket_batch,
             str(search_params.compute_dtype),
             float(search_params.local_recall_target),
             float(search_params.merge_recall_target),
@@ -921,7 +921,7 @@ def sharded_ivf_row_search(
         d, i = ivf_flat._ivf_search(
             q, centers[0], storage[0], indices[0], list_sizes[0],
             int(k), n_probes, metric, group,
-            int(search_params.bucket_batch), 0,
+            int(search_params.bucket_batch),
             str(search_params.compute_dtype),
             float(search_params.local_recall_target),
             float(search_params.merge_recall_target),

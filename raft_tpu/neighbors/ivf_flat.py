@@ -48,7 +48,6 @@ from raft_tpu.neighbors.common import (
     as_filter,
     filter_keep,
     merge_topk,
-    resolve_filter_bits,
     sentinel_for,
 )
 from raft_tpu.matrix.select_k import select_k
@@ -522,9 +521,69 @@ def unbucketize_merge(
     )
 
 
+@functools.partial(jax.jit, static_argnames=("out_of_range",))
+def _build_slot_keep(filter_bits, filter_nbits, indices, *,
+                     out_of_range: str = "drop"):
+    """The list scan's per-slot keep-mask: int32 ``[n_lists, cap]``, 1
+    where the slot's id passes the filter (``filter_keep``, under its
+    ``filter.keep_mask`` scope). ``filter_nbits`` is traced, so one
+    program serves every filter of a word count over an index shape."""
+    return filter_keep(filter_bits, filter_nbits, indices,
+                       out_of_range=out_of_range).astype(jnp.int32)
+
+
+# per-slot keep-masks an index keeps: the last few filters' masks
+_SLOT_KEEP_MAX = 4
+
+
+def _slot_keep(filt, index: "Index"):
+    """The per-slot keep-mask of ``filt`` over ``index`` (None when
+    unfiltered), built once per (bitset, ``out_of_range``, slot-id array
+    ``index.indices``) and reused by every later search with the same.
+    An entry is used only for the very words array and ``n_bits`` it was
+    built from: ``Bitset.set``/``flip``/``resize`` replace the words, so
+    a mutated bitset misses, as does an extended index (a new
+    ``indices``). The masks live on the index, the last
+    ``_SLOT_KEEP_MAX`` filters' at most, and go with it. Counts
+    ``filter.slot_keep_hits`` / ``filter.slot_keep_misses``; a build runs
+    under the ``filter.slot_keep_build`` span."""
+    bits = getattr(filt, "bitset", None)
+    if bits is None:
+        return None
+    oor = getattr(filt, "out_of_range", "drop")
+    indices = index.indices
+    if isinstance(bits.bits, jax.core.Tracer) or isinstance(
+            indices, jax.core.Tracer):
+        # under an outer jit: part of the caller's program, never cached
+        return _build_slot_keep(bits.bits, int(bits.n_bits), indices,
+                                out_of_range=oor)
+    key = (id(bits), oor)
+    masks = getattr(index, "_slot_keep_masks", {})
+    hit = masks.get(key)
+    if (hit is not None and hit[0] is bits.bits
+            and hit[1] == int(bits.n_bits) and hit[2] is indices):
+        obs.counter("filter.slot_keep_hits")
+        return hit[3]
+    obs.counter("filter.slot_keep_misses")
+    with obs.span("filter.slot_keep_build", n_bits=int(bits.n_bits),
+                  slots=int(indices.size)):
+        mask = _build_slot_keep(bits.bits, int(bits.n_bits), indices,
+                                out_of_range=oor)
+    # copied and rebound, never changed in place: a concurrent search
+    # reads the old dict or the new one, and a racing build costs one
+    # more miss at worst
+    live = {k: v for k, v in masks.items()
+            if k != key and v[2] is indices}
+    live[key] = (bits.bits, int(bits.n_bits), indices, mask)
+    while len(live) > _SLOT_KEEP_MAX:
+        live.pop(next(iter(live)))
+    index._slot_keep_masks = live
+    return mask
+
+
 @functools.partial(
     jax.jit,
-    static_argnums=(5, 6, 7, 8, 9, 10, 11, 12, 13),
+    static_argnums=(5, 6, 7, 8, 9, 10, 11, 12),
     static_argnames=("scan_impl",),
 )
 def _ivf_search(
@@ -538,15 +597,17 @@ def _ivf_search(
     metric_val: int,
     group: int,
     bucket_batch: int,
-    filter_nbits: int,
     compute_dtype: str = "bf16",
     local_recall_target: float = 0.95,
     merge_recall_target: float = 1.0,
     data_norms=None,
-    filter_bits=None,
+    slot_keep=None,
     *,
     scan_impl: str = "xla",
 ):
+    """The search program. ``slot_keep`` is the per-slot keep-mask
+    (int32 ``[n_lists, cap]``, nonzero = the slot's id passes the
+    filter; :func:`_slot_keep`), or None for an unfiltered search."""
     metric = DistanceType(metric_val)
     select_min = is_min_close(metric)
     C, cap, d = storage.shape
@@ -615,8 +676,8 @@ def _ivf_search(
 
         col_ok = (jnp.arange(cap)[None, :] < sizes[:, None])[:, None, :]
         valid = col_ok & (bq >= 0)[:, :, None]
-        if filter_bits is not None:
-            valid = valid & filter_keep(filter_bits, filter_nbits, ids)[:, None, :]
+        if slot_keep is not None:
+            valid = valid & (slot_keep[bl] != 0)[:, None, :]
         dist = jnp.where(valid, dist, sentinel)
         ld, lsel = merge_topk(
             dist, jnp.broadcast_to(ids[:, None, :], dist.shape), kl, select_min,
@@ -650,13 +711,9 @@ def _ivf_search(
                 mk, qaux = ivf_scan.L2, qnorm[qsafe_b]
                 pn2 = (data_norms if data_norms is not None else
                        jnp.sum(storage.astype(jnp.float32) ** 2, axis=2))
-            keep = None
-            if filter_bits is not None:
-                keep = filter_keep(filter_bits, filter_nbits,
-                                   indices).astype(jnp.int32)
             out_d, cand_i = ivf_scan.fused_list_scan_topk(
                 storage, indices, list_sizes, bucket_list, qv, qaux, pn2,
-                keep, k=kl, metric_kind=mk,
+                slot_keep, k=kl, metric_kind=mk,
                 approx=local_recall_target < 1.0,
                 recall_target=float(local_recall_target),
                 interpret=scan_impl == "pallas_interpret",
@@ -718,11 +775,6 @@ def search(
     with obs.entry_span("search", "ivf_flat",
                         queries=int(queries.shape[0]), k=int(k),
                         n_probes=n_probes) as _sp:
-        filt = as_filter(prefilter)
-        # materializes "keep"-mode tombstone filters (new ids past the
-        # filter default to kept) for the drop-semantics scan kernels —
-        # docs/serving.md §5; index.size stays lazy (device reduction)
-        bits = resolve_filter_bits(filt, lambda: index.size)
         scan_impl = _resolve_scan_impl(
             str(search_params.scan_impl), cap, min(int(k), cap),
             approx=float(search_params.local_recall_target) < 1.0,
@@ -738,6 +790,9 @@ def search(
             int(queries.shape[0]), n_probes, index.n_lists,
             int(search_params.query_group),
         )
+        # out_of_range is applied per slot ("keep" admits ids past the
+        # filter, docs/serving.md §5)
+        slot_keep = _slot_keep(as_filter(prefilter), index)
         return _ivf_search(
             queries,
             index.centers,
@@ -749,12 +804,11 @@ def search(
             int(index.metric),
             group,
             int(search_params.bucket_batch),
-            0 if bits is None else int(bits.n_bits),
             str(search_params.compute_dtype),
             float(search_params.local_recall_target),
             float(search_params.merge_recall_target),
             index.data_norms,
-            None if bits is None else bits.bits,
+            slot_keep,
             scan_impl=scan_impl,
         )
 

@@ -75,6 +75,7 @@ from raft_tpu.serve.registry import Generation, Registry
 TRACKED_JITS = (
     ("raft_tpu.neighbors.brute_force", "_search"),
     ("raft_tpu.neighbors.ivf_flat", "_ivf_search"),
+    ("raft_tpu.neighbors.ivf_flat", "_build_slot_keep"),
     ("raft_tpu.neighbors.ivf_flat", "_coarse_margins"),
     ("raft_tpu.neighbors.ivf_pq", "_pq_search"),
     ("raft_tpu.neighbors.cagra", "_beam_search"),

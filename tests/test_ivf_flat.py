@@ -364,3 +364,117 @@ def test_bf16_storage_serialize_roundtrip(dataset, tmp_path):
     _, i1 = ivf_flat.search(sp, idx, q[:32], 5)
     _, i2 = ivf_flat.search(sp, loaded, q[:32], 5)
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
+
+
+# ---------------------------------------------------------------------------
+# the list scan's per-slot keep-mask, built once per filter and index
+# ---------------------------------------------------------------------------
+
+
+def _keep_oracle(bits_dense, ids, out_of_range):
+    """Numpy keep decision per slot: the id's bit where the filter
+    covers it, ``out_of_range`` past it, never a negative id."""
+    n = bits_dense.shape[0]
+    inside = (ids >= 0) & (ids < n)
+    keep = np.where(inside, bits_dense[np.clip(ids, 0, max(n - 1, 0))],
+                    out_of_range == "keep")
+    return keep & (ids >= 0)
+
+
+def _fresh_search(sp, index, q, k, bits, out_of_range, impl):
+    """The search with a keep-mask computed afresh for this call, the
+    uncached reference."""
+    import jax.numpy as jnp
+
+    from raft_tpu.neighbors.common import filter_keep
+
+    keep = filter_keep(bits.bits, bits.n_bits, index.indices,
+                       out_of_range=out_of_range).astype(jnp.int32)
+    group = ivf_flat.adaptive_query_group(q.shape[0], sp.n_probes,
+                                          index.n_lists, sp.query_group)
+    return ivf_flat._ivf_search(
+        jnp.asarray(q), index.centers, index.storage, index.indices,
+        index.list_sizes, k, sp.n_probes, int(index.metric), group,
+        sp.bucket_batch, sp.compute_dtype, sp.local_recall_target,
+        sp.merge_recall_target, index.data_norms, keep, scan_impl=impl)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("case", ["deleted-drop", "deleted-keep",
+                                  "extended-drop", "extended-keep",
+                                  "all-kept"])
+def test_cached_slot_keep_matches_a_fresh_mask(dataset, case, impl):
+    """A filtered search through the cached per-slot mask is
+    bit-identical to one with the mask computed afresh, on the first
+    call (a miss) and the second (a hit), and the mask is the keep
+    decision of every slot's id."""
+    from raft_tpu.neighbors.common import BitsetFilter
+
+    x, q = dataset
+    q = q[:20]
+    k, n = 10, x.shape[0]
+    rng = np.random.default_rng(11)
+    mode = "keep" if case.endswith("keep") else "drop"
+    if case.startswith("extended"):
+        # the filter covers the first half; the index is extended after
+        index = _build(x[: n // 2])
+        allowed = rng.random(n // 2) > 0.3
+        bits = Bitset.from_dense(allowed)
+        index = ivf_flat.extend(index, x[n // 2:])
+    else:
+        index = _build(x)
+        allowed = (np.ones(n, bool) if case == "all-kept"
+                   else rng.random(n) > 0.3)
+        bits = Bitset.from_dense(allowed)
+    filt = BitsetFilter(bits, out_of_range=mode)
+    sp = ivf_flat.SearchParams(n_probes=8, query_group=64, bucket_batch=4,
+                               compute_dtype="f32", local_recall_target=1.0,
+                               scan_impl=impl)
+    want_d, want_i = _fresh_search(sp, index, q, k, bits, mode, impl)
+    for _ in range(2):
+        d, i = ivf_flat.search(sp, index, q, k, prefilter=filt)
+        np.testing.assert_array_equal(np.asarray(i), np.asarray(want_i))
+        np.testing.assert_array_equal(np.asarray(d), np.asarray(want_d))
+    ids = np.asarray(index.indices)
+    np.testing.assert_array_equal(
+        np.asarray(ivf_flat._slot_keep(filt, index)) != 0,
+        _keep_oracle(allowed, ids, mode))
+    got = np.asarray(i)
+    assert _keep_oracle(allowed, got[got >= 0], mode).all()
+
+
+@pytest.mark.parametrize("change", ["none", "set", "flip", "extend",
+                                    "out_of_range"])
+def test_slot_keep_cache_misses_exactly_on_a_change(dataset, change):
+    """The cached mask is reused until the bitset's content version
+    (``set``/``flip``), the index's slot-id array (``extend``) or the
+    filter's ``out_of_range`` mode changes; the rebuilt mask is the
+    fresh one."""
+    import jax.numpy as jnp
+
+    from raft_tpu.neighbors.common import BitsetFilter, filter_keep
+
+    x, _ = dataset
+    n = x.shape[0] // 2
+    index = _build(x[:n])
+    bits = Bitset.from_dense(np.arange(n) % 3 != 0)
+    filt = BitsetFilter(bits)
+    first = ivf_flat._slot_keep(filt, index)
+    assert ivf_flat._slot_keep(BitsetFilter(bits), index) is first
+    if change == "set":
+        bits.set(jnp.asarray([1, 4]), False)
+    elif change == "flip":
+        bits.flip()
+    elif change == "extend":
+        index = ivf_flat.extend(index, x[n:])
+    elif change == "out_of_range":
+        filt = BitsetFilter(bits, out_of_range="keep")
+    again = ivf_flat._slot_keep(filt, index)
+    if change == "none":
+        assert again is first
+        return
+    assert again is not first
+    assert ivf_flat._slot_keep(filt, index) is again
+    fresh = filter_keep(bits.bits, bits.n_bits, index.indices,
+                        out_of_range=filt.out_of_range)
+    np.testing.assert_array_equal(np.asarray(again) != 0, np.asarray(fresh))

@@ -409,8 +409,9 @@ _IVF_STAGES = ("ivf.coarse", "ivf.bucketize", "ivf.scan", "ivf.merge")
 def test_search_program_names_its_device_stages(monkeypatch, algo, impl):
     """Each stage of the IVF search programs carries its named scope in
     the compiled program's op metadata, the keep-mask of a filtered
-    search under the scan's: what a profiler trace names the device ops
-    by."""
+    search under the scan's (IVF-PQ) or in IVF-Flat's per-slot mask
+    program, built once per filter and index: what a profiler trace
+    names the device ops by."""
     from raft_tpu.core.bitset import Bitset
     from raft_tpu.neighbors import ivf_flat, ivf_pq
 
@@ -435,8 +436,15 @@ def test_search_program_names_its_device_stages(monkeypatch, algo, impl):
     for stage in _IVF_STAGES:
         assert f'op_name="jit({prog})/{stage}/' in text, stage
     assert f"jit({prog})/ivf.scan/" in text
-    assert re.search(rf'op_name="jit\({prog}\)/ivf\.scan/[^"]*'
-                     r'filter\.keep_mask/', text)
+    if algo == "ivf_flat":
+        bits = Bitset.from_dense(jnp.asarray(keep))   # a fresh mask build
+        text = _compiled_text(monkeypatch, mod, "_build_slot_keep",
+                              lambda: mod.search(sp, index, q, 4,
+                                                 prefilter=bits))
+        assert 'op_name="jit(_build_slot_keep)/filter.keep_mask/' in text
+    else:
+        assert re.search(rf'op_name="jit\({prog}\)/ivf\.scan/[^"]*'
+                         r'filter\.keep_mask/', text)
 
 
 def test_rerank_program_names_its_device_stage(monkeypatch):

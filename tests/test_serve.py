@@ -787,6 +787,79 @@ def test_serve_metrics_emitted(data):
         obs.reset()
 
 
+def _served_ivf_flat(srv, dim=20, rows=640):
+    """An IVF-Flat index of a shape no other test here serves, published
+    without warm-up; returns its rows and queries."""
+    rng = np.random.default_rng(dim)
+    x = rng.standard_normal((rows, dim)).astype(np.float32)
+    q = rng.standard_normal((16, dim)).astype(np.float32)
+    index = ivf_flat.build(ivf_flat.IndexParams(n_lists=8,
+                                                kmeans_n_iters=4), x)
+    srv.add_index("default", index, algo="ivf_flat",
+                  search_params=ivf_flat.SearchParams(n_probes=4))
+    return x, q
+
+
+def test_ivf_flat_bring_up_adds_one_program_for_the_keep_mask():
+    """Bringing up a served IVF-Flat index the way the benchmark does
+    (one block request per bucket) traces one search program per bucket,
+    the filtered one, and at most one more program: the per-slot
+    keep-mask builder. More batches and a delete trace nothing, and the
+    deleted id never comes back."""
+    params = _params(max_k=4, warmup=False)
+    with serve.Server(params) as srv:
+        _, q = _served_ivf_flat(srv)
+        ladder = serve.bucket_ladder(params.max_batch_rows)
+        before = serve.trace_cache_sizes()
+        for b in ladder:
+            srv.submit(q[:b], 4).result(timeout=600)
+        after = serve.trace_cache_sizes()
+        grew = {name: n - before.get(name, 0) for name, n in after.items()
+                if n != before.get(name, 0)}
+        assert grew.pop("ivf_flat._build_slot_keep", 0) <= 1
+        assert grew == {"ivf_flat._ivf_search": len(ladder)}, grew
+
+        for rows in (3, 1, 16, 7):
+            srv.search(q[:rows], 4)
+        _, i = srv.search(q[:1], 4)
+        victim = int(np.asarray(i)[0, 0])
+        srv.delete([victim])
+        for rows in (1, 5, 16):
+            _, i = srv.search(q[:rows], 4)
+            assert victim not in np.asarray(i)
+        assert serve.trace_cache_sizes() == after
+
+
+def test_keep_mask_counters_miss_once_per_mutation_epoch():
+    """The per-slot keep-mask is built on the first batch of a mutation
+    epoch and reused by every later one; a delete starts a new epoch."""
+    from raft_tpu import obs
+
+    def counts():
+        m = obs.snapshot(runtime_gauges=False)["metrics"]
+        return tuple(sum(p["value"] for p in m.get(name, {}).get(
+            "points", [])) for name in ("filter.slot_keep_hits",
+                                        "filter.slot_keep_misses"))
+
+    obs.set_mode("on")
+    try:
+        obs.reset()
+        with serve.Server(_params(max_k=4, warmup=False)) as srv:
+            _, q = _served_ivf_flat(srv, dim=12)
+            srv.search(q[:2], 4)
+            assert counts() == (0, 1)
+            for rows in (1, 4, 9):
+                srv.search(q[:rows], 4)
+            assert counts() == (3, 1)
+            srv.delete([5])
+            srv.search(q[:3], 4)
+            srv.search(q[:3], 4)
+            assert counts() == (4, 2)
+    finally:
+        obs.set_mode(None)
+        obs.reset()
+
+
 # ---------------------------------------------------------------------------
 # graft-race regressions (ISSUE 7): races found dogfooding GL010/GL011
 # ---------------------------------------------------------------------------
