@@ -17,6 +17,17 @@ a file of its own, found by name:
 * ``benchmark/costs/<kernel>.py``: the operations and bytes a kernel's
   roofline share divides by.
 
+A cell's ``chips`` says how many chips it spans. A one-chip cell's rows
+and queries are arrays on the default device. For a cell on several
+chips the harness builds ``Mesh(devices[:chips], ("shard",))``
+(``harness.MESH_AXIS``, the axis name ``raft_tpu.comms`` takes by
+default) and makes the rows in place sharded by row across it,
+``NamedSharding(mesh, P("shard", None))``, with the queries replicated
+on every chip; no chip holds more than its share of the rows. An entry
+keeps the signatures ``build(cfg, x)`` and ``searcher(cfg, index, x)``
+and reads the mesh from ``x.sharding.mesh``. The reference and the
+control scan each chip's own rows there (:mod:`benchmark.reference`).
+
 The yardstick lives here and nowhere in the program: the data generator
 (:mod:`benchmark.data`), the exact reference (:mod:`benchmark.reference`),
 the peaks (:mod:`benchmark.peaks`) and the trace reduction

@@ -23,6 +23,10 @@ _PROGRAM = "/jax/core/compile/backend_compile_duration"
 _CACHE_LOADED = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
+# the axis of a several-chip cell's mesh, the name raft_tpu.comms takes
+MESH_AXIS = "shard"
+
+
 class NoAccelerator(RuntimeError):
     pass
 
@@ -211,9 +215,18 @@ def run_cell(tree: str, workload: str, seed: int, seconds: float,
     bench = BenchSpec(tree)
     run = Run(bench, workload, seed, seconds, trace_on)
     cfg, k = run.cfg, int(run.cfg["k"])
-    devs = (check_devices(int(run.workload["chips"])) if require_accelerator
+    chips = int(run.workload["chips"])
+    devs = (check_devices(chips) if require_accelerator
             else jax.devices())
     run.device_kind = devs[0].device_kind
+    mesh = None
+    if chips > 1:
+        from jax.sharding import Mesh
+
+        if len(devs) < chips:
+            raise ValueError(f"{workload} needs {chips} devices, JAX has "
+                             f"{len(devs)}")
+        mesh = Mesh(devs[:chips], (MESH_AXIS,))
     compiles = _CompileCounter()
 
     if control:
@@ -224,7 +237,7 @@ def run_cell(tree: str, workload: str, seed: int, seconds: float,
         entry = bench.module("entries", cfg["entry"])
     driver = bench.module("drivers", run.traffic["kind"])
 
-    x, q = jax.block_until_ready(data.generate(cfg, seed))
+    x, q = jax.block_until_ready(data.generate(cfg, seed, mesh))
     run.queries = q
     log(f"data made at {time.perf_counter() - t_start:.1f} s")
     readings: dict = {}
@@ -281,9 +294,11 @@ def run_cell(tree: str, workload: str, seed: int, seconds: float,
     if trace_on:
         run.obs = obs.snapshot(runtime_gauges=False)
     obs.set_mode(None)
-    peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
-               for d in devs)
+    per_device = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                  for d in devs[:chips]]
+    peak = max(per_device)
     run.info["peak_bytes_in_use"] = peak
+    run.info["peak_bytes_per_device"] = per_device
     if trace_on and index is not None:
         run.layout = entry.scan_layout(cfg, index)
     driver.close(st)

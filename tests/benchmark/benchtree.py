@@ -28,7 +28,8 @@ def make_tree(tmp, configs=None, traffic=None, workloads=None,
               per_layer=()) -> str:
     """Copy the benchmark's files under ``tmp`` and add ``configs``
     ({name: dict}) and ``traffic`` ({name: dict}); ``workloads`` is a list
-    of (name, config, traffic). The end-to-end metrics are the
+    of (name, config, traffic) or (name, config, traffic, chips), on one
+    chip where the chips are not given. The end-to-end metrics are the
     repository's, with their cell lists pointed at the new cells."""
     tree = str(tmp)
     root = os.path.join(tree, "benchmark")
@@ -50,13 +51,15 @@ def make_tree(tmp, configs=None, traffic=None, workloads=None,
     spec["configs"] = [{"name": n, "source": "tiny", "reduced": [],
                         "file": f"benchmark/configs/{n}.json", "why": "test"}
                        for n in configs]
-    spec["workloads"] = [{"name": n, "config": c, "traffic": t, "chips": 1,
-                          "why": "test"} for n, c, t in workloads]
+    workloads = [tuple(w) + (1,) * (4 - len(w)) for w in workloads]
+    spec["workloads"] = [{"name": n, "config": c, "traffic": t,
+                          "chips": chips, "why": "test"}
+                         for n, c, t, chips in workloads]
     for m in spec["end_to_end"]:
         if "workloads" in m:
             # a metric of batch cells stays with batch cells, and so on
             old = m["workloads"]
-            m["workloads"] = [n for n, _, t in workloads
+            m["workloads"] = [n for n, _, t, _ in workloads
                               if any(_kind_of(o) == traffic[t]["kind"]
                                      for o in old)]
     spec["per_layer"] = list(per_layer)

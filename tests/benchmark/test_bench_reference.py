@@ -1,15 +1,19 @@
 """The benchmark's yardstick on the CPU at tiny sizes: the reference, the
 control, the ivf_scan cost model, the peaks, and the command's refusal
-to run without an accelerator."""
+to run without an accelerator. The reference and the control run on rows
+on one device and on rows sharded across four of the host's devices, as
+a four-chip cell holds them, and give the same answers."""
 
 import os
 import subprocess
 import sys
 
+import jax
 import numpy as np
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from benchmark import data, peaks, reference
+from benchmark import data, harness, peaks, reference
 from benchtree import REPO
 
 sys.path.insert(0, os.path.join(REPO, "tests"))
@@ -21,6 +25,15 @@ def small():
     x, q = data.generate({"rows": 6000, "dim": 32, "queries": 200,
                           "data_seed": 1, "intrinsic_dim": 8}, 3)
     return x, q
+
+
+def _on_chips(x, chips: int):
+    """``x`` as a cell on ``chips`` chips holds it: on one device, or
+    sharded by row across a mesh of ``chips`` devices."""
+    if chips == 1:
+        return x
+    mesh = Mesh(np.array(jax.devices()[:chips]), (harness.MESH_AXIS,))
+    return jax.device_put(x, NamedSharding(mesh, P(harness.MESH_AXIS, None)))
 
 
 def test_seed_draws_the_queries_of_one_fixed_dataset():
@@ -36,48 +49,69 @@ def test_seed_draws_the_queries_of_one_fixed_dataset():
     assert not np.array_equal(np.asarray(xa), np.asarray(xc))
 
 
+@pytest.mark.parametrize("chips", [1, 4])
 @pytest.mark.parametrize("width", [16, 6000])
-def test_reference_equals_blocked_oracle(small, width):
+def test_reference_equals_blocked_oracle(small, width, chips):
     x, q = small
-    d, i, _ = reference.exact_knn(q, x, 10, width=width, chunk=1000,
-                                  query_block=64)
+    d, i, _ = reference.exact_knn(q, _on_chips(x, chips), 10, width=width,
+                                  chunk=1000, query_block=64)
     od, oi = oracles.exact_knn_blocked(np.asarray(q), x, 10)
     assert np.array_equal(i, oi)
     np.testing.assert_allclose(d, od, rtol=1e-9, atol=1e-9)
 
 
-def test_reference_redoes_unproven_queries(small):
+@pytest.mark.parametrize("chips", [1, 4])
+def test_reference_redoes_unproven_queries(small, chips):
     x, q = small
     # a shortlist as long as k proves nothing: every query is redone in
     # the direct form, and the answer is still exact
-    d, i, redone = reference.exact_knn(q[:20], x, 10, width=10, chunk=1000)
-    _, oi = oracles.exact_knn_blocked(np.asarray(q[:20]), x, 10)
+    d, i, redone = reference.exact_knn(q[:20], _on_chips(x, chips), 10,
+                                       width=10, chunk=1000)
+    od, oi = oracles.exact_knn_blocked(np.asarray(q[:20]), x, 10)
     assert redone > 0
     assert np.array_equal(i, oi)
+    np.testing.assert_allclose(d, od, rtol=1e-9, atol=1e-9)
 
 
-def test_judge_counts_hits_once_and_flags_wrong_distances(small):
+@pytest.mark.parametrize("chips", [1, 4])
+def test_judge_counts_hits_once_and_flags_wrong_distances(small, chips):
     x, q = small
-    _, gt, _ = reference.exact_knn(q, x, 10, chunk=1000)
+    xc = _on_chips(x, chips)
+    _, gt, _ = reference.exact_knn(q, xc, 10, chunk=1000)
     qidx = np.arange(q.shape[0])
     x64, q64 = np.asarray(x, np.float64), np.asarray(q, np.float64)
     true_d = ((x64[gt] - q64[:, None, :]) ** 2).sum(2)
-    good = reference.judge(q, x, gt, qidx, gt, true_d)
+    good = reference.judge(q, xc, gt, qidx, gt, true_d)
     assert good["recall"] == 1.0 and good["dist_err"] < 1e-6
     dup = np.repeat(gt[:, :1], 10, axis=1)
-    assert reference.judge(q, x, gt, qidx, dup,
+    assert reference.judge(q, xc, gt, qidx, dup,
                            true_d[:, :1].repeat(10, 1))["recall"] == 0.1
-    bad = reference.judge(q, x, gt, qidx, gt, true_d * 1.01)
+    bad = reference.judge(q, xc, gt, qidx, gt, true_d * 1.01)
     assert bad["dist_err"] > 100 * max(good["dist_err"], 1e-7)
+    nan = true_d.copy()
+    nan[7, 3] = np.nan
+    assert reference.judge(q, xc, gt, qidx, gt, nan)["dist_err"] == np.inf
+    # an id past either end, and a missing one (-1), read as on one device
+    odd = gt.copy()
+    odd[3, 2], odd[5, 1] = x.shape[0] + 7, -1
+    for ids in (gt, odd):
+        for d in (true_d, true_d * 1.01):
+            assert (reference.judge(q, xc, gt, qidx, ids, d)
+                    == reference.judge(q, x, gt, qidx, ids, d))
 
 
-def test_control_reads_worse_than_the_reference(small):
+@pytest.mark.parametrize("chips", [1, 4])
+def test_control_reads_worse_than_the_reference(small, chips):
     x, q = small
-    _, gt, _ = reference.exact_knn(q, x, 10, chunk=1000)
-    d, i = reference.lowp_search(q, x, 10, "float8_e4m3fn", chunk=1000)
-    j = reference.judge(q, x, gt, np.arange(q.shape[0]), np.asarray(i),
+    xc = _on_chips(x, chips)
+    _, gt, _ = reference.exact_knn(q, xc, 10, chunk=1000)
+    d, i = reference.lowp_search(q, xc, 10, "float8_e4m3fn", chunk=1000)
+    j = reference.judge(q, xc, gt, np.arange(q.shape[0]), np.asarray(i),
                         np.asarray(d))
     assert j["recall"] < 0.9 and j["dist_err"] > 1e-3
+    d1, i1 = reference.lowp_search(q, x, 10, "float8_e4m3fn", chunk=1000)
+    assert np.array_equal(np.asarray(i), np.asarray(i1))
+    assert np.array_equal(np.asarray(d), np.asarray(d1))
 
 
 def _cost_module():
