@@ -23,7 +23,9 @@ did pre-resilience; callers that want NaN *detection* opt in with
 
 from __future__ import annotations
 
-import functools
+import collections
+import dataclasses
+import threading
 from typing import Optional, Tuple
 
 import jax
@@ -110,6 +112,40 @@ def _finish_partial(out, partial_ok: bool, what: str):
             "to accept partial results plus a coverage fraction"
         )
     return d, i
+
+
+# the programs sharded_ivf_pq_search and sharded_ivf_pq_build hold, the
+# last _HELD_MAX of them, each keyed on everything it is traced from
+_HELD_MAX = 8
+_held: "collections.OrderedDict" = collections.OrderedDict()
+_held_lock = threading.Lock()
+
+
+def _shapes(*trees) -> tuple:
+    """Tree structure (with an index's static fields), shapes and dtypes
+    of ``trees``: what a jitted program is traced at."""
+    leaves, tree = jax.tree_util.tree_flatten(trees)
+    return tree, tuple((tuple(a.shape), str(a.dtype)) for a in leaves)
+
+
+def _hold(key, make):
+    """``make()``'s value for ``key``: made on the first call with the
+    key, then held, so that a repeat call traces nothing. Counts
+    ``sharded.program_hits`` / ``sharded.program_misses``."""
+    with _held_lock:
+        val = _held.get(key)
+        if val is not None:
+            _held.move_to_end(key)
+    if val is not None:
+        obs.counter("sharded.program_hits")
+        return val
+    obs.counter("sharded.program_misses")
+    val = make()
+    with _held_lock:
+        _held[key] = val
+        while len(_held) > _HELD_MAX:
+            _held.popitem(last=False)
+    return val
 
 
 def sharded_knn(
@@ -348,6 +384,16 @@ def sharded_ivf_pq_search(
     ``partial_ok`` composes (an uncovered shard's ``-1`` rows stay
     invalid through the rerank; coverage passes through unchanged).
 
+    A ``rerank_source`` whose rows are sharded by row over a mesh axis
+    (``tiered.RowShardedSource``) is read where it lies: each chip
+    scores the merged candidates it holds, and one all-reduce joins the
+    scores (device stage ``sharded.rerank``; host span
+    ``sharded.rerank`` around the tail's dispatch).
+
+    The jitted program and its compiled plans are held per mesh, axis,
+    argument shapes and statics (:func:`_hold`): a repeat call traces
+    nothing.
+
     ``partial_ok=True`` returns ``(dists, ids, coverage)`` with invalid
     shards masked out of the merge (module docstring).
     """
@@ -431,34 +477,100 @@ def sharded_ivf_pq_search(
 
     has_scales = has_cache and index.cache_scales is not None
     partial = partial_ok or faultinject.has_shard_faults()
+    tail_kind = ("tiered" if src is not None
+                 else "codes" if codes_refine else None)
+
+    args = [queries, index.centers, index.centers_rot, index.rotation,
+            index.pq_centers, index.codes, index.indices, index.list_sizes,
+            index.rec_norms]
+    if has_cache:
+        args.append(index.recon_cache)
+    if has_scales:
+        args.append(index.cache_scales)        # [C, rot] per-list scales
+    if has_scales or has_fac:
+        args.append(index.cache_qnorms if index.cache_qnorms is not None
+                    else index.rec_norms)
+    if has_fac:
+        args.append(index.cache_fac)           # [C, cap] discriminator
+    if partial:
+        args.append(_dead_rank_array())
+
+    statics = dict(
+        k=int(k), k_search=int(k_search), k_merge=int(k_merge),
+        n_probes=n_probes, group=group, bucket_batch=bucket_batch,
+        lut=lut, internal=internal,
+        codebook_kind=int(index.codebook_kind), per_cluster=per_cluster,
+        cache_refine=cache_refine, codes_refine=codes_refine,
+        tail_kind=tail_kind, partial=partial, metric=metric,
+        select_min=select_min,
+        compute_dtype=str(search_params.compute_dtype),
+        local_recall_target=float(search_params.local_recall_target),
+        merge_recall_target=float(search_params.merge_recall_target),
+        pq_dim=int(index.pq_dim), pq_bits=int(index.pq_bits),
+        recon_scale=float(index.recon_scale), refine_ratio=refine_ratio,
+        has_cache=has_cache, has_scales=has_scales, has_fac=has_fac,
+        local_slots=local_lists * cap)
+    key = ("ivf_pq_search", mesh, axis_name, _shapes(args),
+           tuple(sorted(statics.items())))
+    fn, tail_plan, tail_cp = _hold(key, lambda: _ivf_pq_search_program(
+        mesh, axis_name, **statics))
+    if tail_kind == "codes":
+        # the rabitq tail decodes from the full index: bound per call
+        tail_cp = plan_mod.compile(tail_plan, index, k=int(k))
+    with obs.entry_span("search", "sharded_ivf_pq",
+                        queries=int(queries.shape[0]), k=int(k),
+                        shards=int(nshards), refine_ratio=refine_ratio):
+        out = fn(*args)
+        if tail_cp is not None:
+            # router-side rerank over the MERGED shortlist (tiered
+            # fetch of unique rows, rows read on the chips that hold
+            # them, or rabitq slot decode from the packed codes);
+            # uncovered shards' -1 rows stay invalid and sink at the
+            # exact ranking
+            md, mi = out[0], out[1]
+            with obs.span("sharded.rerank", kc=int(mi.shape[-1])):
+                rd, ri = tail_cp(queries, extra={"candidates": (md, mi),
+                                                 "source": src})
+            out = (rd, ri) + tuple(out[2:])
+    if partial:
+        return _finish_partial(out, partial_ok, "sharded_ivf_pq_search")
+    _record_full_coverage("sharded_ivf_pq_search")
+    return out
+
+
+def _ivf_pq_search_program(mesh, axis_name, *, k, k_search, k_merge,
+                           n_probes, group, bucket_batch, lut, internal,
+                           codebook_kind, per_cluster, cache_refine,
+                           codes_refine, tail_kind, partial, metric,
+                           select_min, compute_dtype, local_recall_target,
+                           merge_recall_target, pq_dim, pq_bits,
+                           recon_scale, refine_ratio, has_cache,
+                           has_scales, has_fac, local_slots):
+    """The jitted list-sharded program of :func:`sharded_ivf_pq_search`,
+    its router tail plan, and that plan compiled where it binds nothing
+    of a call (the tiered tail takes its source per call; the rabitq
+    codes tail binds the index, so the caller compiles it). Made from
+    the call's statics alone, so that it can be held across calls."""
+    from raft_tpu.neighbors import ivf_pq
 
     # the pipeline as DATA (raft_tpu.plan): the pre-merge subplan runs
     # per worker inside shard_map, the rerank tail (if any) once on the
     # router — split_at_merge cuts at the collective
-    tail_kind = ("tiered" if src is not None
-                 else "codes" if codes_refine else None)
     p = plan_mod.sharded_ivf_pq_plan(
-        int(k), int(k_search), int(k_merge),
-        local_rerank=cache_refine, tail=tail_kind)
+        k, k_search, k_merge, local_rerank=cache_refine, tail=tail_kind)
     head_plan, tail_plan = plan_mod.split_at_merge(p)
     head_cp = plan_mod.compile(
-        head_plan, index, k=int(k), search_params=search_params,
-        refine_ratio=refine_ratio,
+        head_plan, None, k=k, refine_ratio=refine_ratio,
         n_probes=n_probes, metric=metric, group=group,
-        bucket_batch=bucket_batch,
-        codebook_kind=int(index.codebook_kind),
-        compute_dtype=str(search_params.compute_dtype),
-        local_recall_target=float(search_params.local_recall_target),
-        merge_recall_target=float(search_params.merge_recall_target),
-        lut=lut, internal=internal,
-        pq_dim=int(index.pq_dim), pq_bits=int(index.pq_bits),
-        recon_scale=float(index.recon_scale),
-        axis_name=axis_name, select_min=select_min)
-    tail_cp = (None if tail_plan is None
-               else plan_mod.compile(tail_plan, index, k=int(k),
-                                     source=src))
-
-    local_slots = local_lists * cap
+        bucket_batch=bucket_batch, codebook_kind=codebook_kind,
+        compute_dtype=compute_dtype,
+        local_recall_target=local_recall_target,
+        merge_recall_target=merge_recall_target,
+        lut=lut, internal=internal, pq_dim=pq_dim, pq_bits=pq_bits,
+        recon_scale=recon_scale, axis_name=axis_name,
+        select_min=select_min)
+    tail_cp = (plan_mod.compile(tail_plan, None, k=k, metric=metric)
+               if tail_kind == "tiered" else None)
 
     def local(q, centers, centers_rot, rotation, pq_centers, codes,
               indices, list_sizes, rec_norms, *rest):
@@ -481,7 +593,7 @@ def sharded_ivf_pq_search(
             search_ids = indices
         arrays = (q, centers, centers_rot, rotation, pq_centers, codes,
                   search_ids, list_sizes, rec_norms, None, cache,
-                  jnp.float32(index.recon_scale), scales, qnorms, fac)
+                  jnp.float32(recon_scale), scales, qnorms, fac)
         extra = {"indices": indices, "cache": cache, "scales": scales}
         if partial:
             cov = {}
@@ -497,9 +609,6 @@ def sharded_ivf_pq_search(
             return md, mi, _coverage(cov["valid"], axis_name)
         return md, mi
 
-    args = [queries, index.centers, index.centers_rot, index.rotation,
-            index.pq_centers, index.codes, index.indices, index.list_sizes,
-            index.rec_norms]
     in_specs = [
         P(),                          # queries replicated
         P(axis_name, None),           # centers
@@ -512,46 +621,23 @@ def sharded_ivf_pq_search(
         P(axis_name, None),           # rec_norms
     ]
     if has_cache:
-        args.append(index.recon_cache)
         in_specs.append(P(axis_name, None, None))
     if has_scales:
-        args.append(index.cache_scales)        # [C, rot] per-list scales
         in_specs.append(P(axis_name, None))
     if has_scales or has_fac:
-        qn = (index.cache_qnorms if index.cache_qnorms is not None
-              else index.rec_norms)
-        args.append(qn)
         in_specs.append(P(axis_name, None))
     if has_fac:
-        args.append(index.cache_fac)           # [C, cap] discriminator
         in_specs.append(P(axis_name, None))
     if partial:
-        args.append(_dead_rank_array())
         in_specs.append(P())
-
-    fn = shard_map(
+    fn = jax.jit(shard_map(
         local,
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(), P()) + ((P(),) if partial else ()),
         check_vma=False,
-    )
-    with obs.entry_span("search", "sharded_ivf_pq",
-                        queries=int(queries.shape[0]), k=int(k),
-                        shards=int(nshards), refine_ratio=refine_ratio):
-        out = jax.jit(fn)(*args)
-        if tail_cp is not None:
-            # router-side rerank over the MERGED shortlist (tiered
-            # fetch of unique rows, or rabitq slot decode from the
-            # packed codes); uncovered shards' -1 rows stay invalid
-            # and sink at the exact ranking
-            md, mi = out[0], out[1]
-            rd, ri = tail_cp(queries, extra={"candidates": (md, mi)})
-            out = (rd, ri) + tuple(out[2:])
-    if partial:
-        return _finish_partial(out, partial_ok, "sharded_ivf_pq_search")
-    _record_full_coverage("sharded_ivf_pq_search")
-    return out
+    ))
+    return fn, tail_plan, tail_cp
 
 
 def sharded_ivf_pq_build(
@@ -569,7 +655,10 @@ def sharded_ivf_pq_build(
     in ``sharded_ivf_pq_search`` (its C/S contiguous lists) from the
     all-gathered codes, so the list arrays come back list-sharded and no
     device ever holds all of them; at DEEP-1B scale the all-gather of
-    the codes would become an all-to-all.
+    the codes would become an all-to-all. The norms and the int8
+    decoded-residual cache are built on each device from its own lists
+    and come back list-sharded too. The three device programs are held
+    across builds of the same shapes (:func:`_hold`).
 
     Returns a regular ``ivf_pq.Index`` with GLOBAL row ids; pass it to
     ``sharded_ivf_pq_search`` to search list-sharded over the mesh.
@@ -599,27 +688,91 @@ def sharded_ivf_pq_build(
     # transients (rotated rows, residuals) stay at a chunk's size
     chunk = next(c for c in range(min(rows, 1 << 20), 0, -1)
                  if rows % c == 0)
+    labels, packed = _hold(
+        ("ivf_pq_encode", mesh, axis_name, _shapes(dataset, quant), chunk),
+        lambda: _encode_program(mesh, axis_name, rows, chunk))(dataset,
+                                                                quant)
 
-    def local_encode(part):
-        labels, packed = jax.lax.map(
-            lambda blk: ivf_pq.encode(quant, blk),
-            part.reshape(rows // chunk, chunk, dim))
-        return labels.reshape(rows), packed.reshape(rows, -1)
-
-    fn = shard_map(
-        local_encode,
-        mesh=mesh,
-        in_specs=(P(axis_name, None),),
-        out_specs=(P(axis_name), P(axis_name, None)),
-        check_vma=False,
-    )
-    labels, packed = jax.jit(fn)(dataset)
-
-    from raft_tpu.neighbors.ivf_flat import _aligned_cap, _pack_lists
+    from raft_tpu.neighbors.ivf_flat import _aligned_cap
 
     counts = np.bincount(np.asarray(labels), minlength=quant.n_lists)
     cap = _aligned_cap(int(counts.max()))
     lists = quant.n_lists // nshards
+    codes_packed, indices, list_sizes = _hold(
+        ("ivf_pq_pack", mesh, axis_name, _shapes(labels, packed), lists,
+         cap),
+        lambda: _pack_program(mesh, axis_name, n, lists, cap))(labels,
+                                                               packed)
+    # each device scores its own lists: a scan over the list axis of the
+    # list-sharded codes would gather them whole onto every device (4 x
+    # v5e at 40M rows: 9.6 GB, RESOURCE_EXHAUSTED)
+    per_cluster = (int(params.codebook_kind)
+                   == ivf_pq.codebook_gen.PER_CLUSTER)
+    books = P(axis_name) if per_cluster else P()
+    statics = (int(params.codebook_kind), quant.pq_dim, int(params.pq_bits))
+    rec_norms = _hold(
+        ("ivf_pq_rec_norms", mesh, axis_name,
+         _shapes(codes_packed, quant.pq_centers), statics),
+        lambda: jax.jit(shard_map(
+            lambda codes, bk: ivf_pq._rec_norms(codes, bk, *statics),
+            mesh=mesh, in_specs=(P(axis_name), books),
+            out_specs=P(axis_name), check_vma=False,
+        )))(codes_packed, quant.pq_centers)
+    index = dataclasses.replace(quant, codes=codes_packed, indices=indices,
+                                list_sizes=list_sizes, rec_norms=rec_norms)
+    if ivf_pq._resolve_cache_kind(index) != "i8":
+        return ivf_pq._attach_cache(index)
+    # the int8 cache, built the same way list by list on each device; its
+    # scale is the bound of the whole codebook, so every device's agrees
+    scale = ivf_pq._recon_scale(quant.pq_centers)
+    cache = _hold(
+        ("ivf_pq_cache", mesh, axis_name,
+         _shapes(codes_packed, quant.pq_centers), statics),
+        lambda: jax.jit(shard_map(
+            lambda codes, bk, sc: ivf_pq._recon_cache_lists(
+                codes, bk, sc, *statics),
+            mesh=mesh, in_specs=(P(axis_name), books, P()),
+            out_specs=P(axis_name), check_vma=False,
+        )))(codes_packed, quant.pq_centers, scale)
+    return dataclasses.replace(
+        index, recon_cache=cache, recon_scale=float(scale),
+        cache_scales=None, cache_qnorms=None, cache_fac=None)
+
+
+def _encode_program(mesh, axis_name, rows: int, chunk: int):
+    """Each device labels and PQ-encodes its row shard against the
+    quantizers (an argument, replicated), ``chunk`` rows at a time: a
+    chunk is sliced from the shard as it lies (a reshape of the whole
+    shard would copy it to another layout first)."""
+    from raft_tpu.neighbors import ivf_pq
+
+    def local_encode(part, quant):
+        def encode(blk):
+            return ivf_pq.encode(quant, blk)
+
+        def step(c, out):
+            blk = jax.lax.dynamic_slice_in_dim(part, c * chunk, chunk, 0)
+            return tuple(jax.lax.dynamic_update_slice_in_dim(
+                o, r, c * chunk, 0) for o, r in zip(out, encode(blk)))
+
+        shapes = jax.eval_shape(encode, part[:chunk])
+        init = tuple(jnp.zeros((rows,) + sd.shape[1:], sd.dtype)
+                     for sd in shapes)
+        return jax.lax.fori_loop(0, rows // chunk, step, init)
+
+    return jax.jit(shard_map(
+        local_encode,
+        mesh=mesh,
+        in_specs=(P(axis_name, None), P()),
+        out_specs=(P(axis_name), P(axis_name, None)),
+        check_vma=False,
+    ))
+
+
+def _pack_program(mesh, axis_name, n: int, lists: int, cap: int):
+    """Each device packs the ``lists`` lists it owns from the
+    all-gathered labels and codes."""
+    from raft_tpu.neighbors.ivf_flat import _pack_lists
 
     def local_pack(lab, codes):
         # rows of other devices' lists get label ``lists``: dropped
@@ -630,35 +783,12 @@ def sharded_ivf_pq_build(
         return _pack_lists(codes, jnp.where(mine, lab - lo, lists),
                            jnp.arange(n, dtype=jnp.int32), lists, cap)
 
-    codes_packed, indices, list_sizes = jax.jit(shard_map(
+    return jax.jit(shard_map(
         local_pack,
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name, None)),
         out_specs=(P(axis_name),) * 3,
         check_vma=False,
-    ))(labels, packed)
-    # each device scores its own lists: a scan over the list axis of the
-    # list-sharded codes would gather them whole onto every device (4 x
-    # v5e at 40M rows: 9.6 GB, RESOURCE_EXHAUSTED)
-    per_cluster = (int(params.codebook_kind)
-                   == ivf_pq.codebook_gen.PER_CLUSTER)
-    rec_norms = jax.jit(shard_map(
-        lambda codes, books: ivf_pq._rec_norms(
-            codes, books, int(params.codebook_kind), quant.pq_dim,
-            int(params.pq_bits)),
-        mesh=mesh,
-        in_specs=(P(axis_name), P(axis_name) if per_cluster else P()),
-        out_specs=P(axis_name),
-        check_vma=False,
-    ))(codes_packed, quant.pq_centers)
-    import dataclasses as _dc
-
-    return ivf_pq._attach_cache(_dc.replace(
-        quant,
-        codes=codes_packed,
-        indices=indices,
-        list_sizes=list_sizes,
-        rec_norms=rec_norms,
     ))
 
 

@@ -1722,15 +1722,29 @@ def attach_rabitq_cache(index: Index) -> Index:
     )
 
 
+def _recon_scale(pq_centers):
+    """The int8 cache's dequant scale, bounded by the codebook itself
+    (every reconstructed component IS a codebook entry), so no data pass
+    is needed."""
+    return jnp.maximum(jnp.max(jnp.abs(pq_centers)), 1e-30) / 127.0
+
+
 @functools.partial(jax.jit, static_argnums=(2, 3, 4))
 def _recon_cache_scan(codes_packed, pq_centers, codebook_kind: int,
                       pq_dim: int, pq_bits: int):
     """int8-quantized decoded residuals per stored vector ([C, cap,
-    rot_dim]), scanned over lists. The dequant scale is bounded by the
-    codebook itself (every reconstructed component IS a codebook entry),
-    so no data pass is needed."""
+    rot_dim]) and their scale (:func:`_recon_scale`)."""
+    scale = _recon_scale(pq_centers)
+    return _recon_cache_lists(codes_packed, pq_centers, scale,
+                              codebook_kind, pq_dim, pq_bits), scale
+
+
+def _recon_cache_lists(codes_packed, pq_centers, scale,
+                       codebook_kind: int, pq_dim: int, pq_bits: int):
+    """:func:`_recon_cache_scan`'s cache at a given ``scale``, scanned
+    over lists (PER_CLUSTER books indexed by list position, so a list
+    shard scores with its own books)."""
     C = codes_packed.shape[0]
-    scale = jnp.maximum(jnp.max(jnp.abs(pq_centers)), 1e-30) / 127.0
 
     def body(_, inp):
         blk, lid = inp                                     # [cap, nw], []
@@ -1746,7 +1760,7 @@ def _recon_cache_scan(codes_packed, pq_centers, codebook_kind: int,
     _, cache = jax.lax.scan(
         body, None, (codes_packed, jnp.arange(C, dtype=jnp.int32))
     )
-    return cache, scale
+    return cache
 
 
 def attach_raw_residual_cache(index: Index, dataset,

@@ -66,9 +66,17 @@ def score_gathered(q, cand_vecs, candidates, k: int,
     the bitwise-identity acceptance (tiered vs full-upload rerank on
     the same shortlist) holds exactly because both paths run THIS
     arithmetic on value-identical operands. Called inside jit."""
-    compute = q.dtype
-    valid = candidates >= 0
+    return select_scored(exact_scores(q, cand_vecs, metric), candidates,
+                         k, metric, q.dtype)
 
+
+def exact_scores(q, cand_vecs, metric: DistanceType):
+    """The distance of every (query, candidate) pair, [m, c]:
+    :func:`score_gathered` before invalid slots sink and the top-k. An
+    entry depends on its own query and row alone, so scores of the rows
+    one chip holds, zero elsewhere, join the other chips' by a sum
+    (``tiered.RowShardedSource``)."""
+    compute = q.dtype
     if metric in (DistanceType.L2Expanded, DistanceType.L2SqrtExpanded):
         # ||q - v||^2 via einsum (MXU): q·v per (query, cand)
         dots = jnp.einsum("md,mcd->mc", q, cand_vecs, preferred_element_type=jnp.float32,
@@ -91,9 +99,13 @@ def score_gathered(q, cand_vecs, candidates, k: int,
         # generic elementwise fallback
         diff = q[:, None, :] - cand_vecs
         d = jnp.sum(jnp.abs(diff) if metric == DistanceType.L1 else diff * diff, axis=2)
+    return d
 
-    sentinel = sentinel_for(metric, compute)
-    d = jnp.where(valid, d, sentinel)
+
+def select_scored(d, candidates, k: int, metric: DistanceType, compute):
+    """Invalid slots (``candidates < 0``) sink to the sentinel of the
+    ``compute`` dtype, then the best ``k`` of the scored candidates."""
+    d = jnp.where(candidates >= 0, d, sentinel_for(metric, compute))
     return merge_topk(d, candidates.astype(jnp.int32), k,
                       is_min_close(metric))
 

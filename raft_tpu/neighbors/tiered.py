@@ -56,11 +56,14 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from raft_tpu import obs, tuning
 from raft_tpu.analysis import lockwatch
 from raft_tpu.distance.types import DistanceType, resolve_metric
+from raft_tpu.neighbors.refine import exact_scores as _exact_scores
 from raft_tpu.neighbors.refine import score_gathered as _score_gathered
+from raft_tpu.neighbors.refine import select_scored as _select_scored
 from raft_tpu.utils.math import next_pow2
 
 # tuning.budget knob: HBM hot-row cache capacity in ROWS (docs/
@@ -169,6 +172,27 @@ class DeviceSource(RerankSource):
                               int(resolve_metric(metric)))
         info = FetchInfo(rung=int(self.dataset.shape[0]))
         return d, i, info
+
+
+class RowShardedSource(DeviceSource):
+    """Device-resident rows sharded by row over one mesh axis (a
+    ``jax.Array`` whose ``NamedSharding`` splits axis 0 and nothing
+    else). Each chip scores the candidates whose rows it holds, from its
+    own shard, and writes 0 for the rest; one ``psum`` over the axis
+    joins the scores, and the top-k is taken on the joined scores. Only
+    one chip holds each row, so the result is bitwise
+    :class:`DeviceSource`'s over the same rows, and no chip ever reads
+    another chip's rows."""
+
+    def __init__(self, dataset, mesh, axis: str):
+        super().__init__(dataset)
+        self.mesh, self.axis = mesh, axis
+
+    def rerank_info(self, queries, candidates, k, metric):
+        d, i = _score_row_sharded(
+            jnp.asarray(queries), self.dataset, jnp.asarray(candidates),
+            int(k), int(resolve_metric(metric)), self.mesh, self.axis)
+        return d, i, FetchInfo(rung=int(self.dataset.shape[0]))
 
 
 class HostArraySource(RerankSource):
@@ -594,6 +618,108 @@ def _score_fetched_hot(queries, block, hot_block, pos, is_hot,
         return _score_gathered(q, cand_vecs, candidates, k, metric)
 
 
+# the row-sharded rerank reads its rows a block at a time: a gather
+# straight from a chip's shard first copies the whole shard to the
+# layout it reads (narrow rows lie column-major on the TPU: 5.8 GB of
+# temporaries at 10M x 96), a block of this many rows is copied instead
+_SHARD_BLOCK_ROWS = 1 << 18
+# candidates taken per step, at most, from the sorted owned candidates
+_SHARD_WINDOW = 1 << 13
+# (query, candidate) pairs scored per block
+_SCORE_ROWS = 1 << 17
+
+
+def _gather_held_rows(xs, rows, mine):
+    """``xs[rows]`` where ``mine``, zero rows elsewhere, as
+    ``[rows.size, w]`` (rows zero-padded to ``w``, whole lanes), with
+    no copy of ``xs`` larger than one block.
+
+    The held candidates are sorted by row; each step copies the block of
+    ``_SHARD_BLOCK_ROWS`` rows that starts at the next unread candidate,
+    gathers up to ``_SHARD_WINDOW`` sorted candidates that lie in it,
+    and scatters them to their places. A step reads at least one
+    candidate, so the loop ends; it runs about once per block that holds
+    a candidate."""
+    n_loc, dim = xs.shape
+    total = rows.size
+    block = min(_SHARD_BLOCK_ROWS, n_loc)
+    window = min(_SHARD_WINDOW, total)
+    align = min(1024, block)
+    key = jnp.where(mine, rows, n_loc).reshape(total).astype(jnp.int32)
+    key, pos = jax.lax.sort(
+        (key, jnp.arange(total, dtype=jnp.int32)), num_keys=1)
+    held = jnp.sum(mine, dtype=jnp.int32)
+    # a window read past the last candidate finds the padding
+    key = jnp.concatenate([key, jnp.full((window,), n_loc, jnp.int32)])
+    pos = jnp.concatenate([pos, jnp.full((window,), total, jnp.int32)])
+    lane = jnp.arange(window, dtype=jnp.int32)
+
+    def step(state):
+        p, out = state
+        first = jax.lax.dynamic_index_in_dim(key, p, keepdims=False)
+        # aligned down by at most a block, so the block holds ``first``
+        start = jnp.minimum(first // align * align, n_loc - block)
+        blk = jax.lax.dynamic_slice_in_dim(xs, start, block, 0)
+        wk = jax.lax.dynamic_slice_in_dim(key, p, window)
+        wp = jax.lax.dynamic_slice_in_dim(pos, p, window)
+        # sorted, so the candidates this block holds are a prefix
+        take = (wk < start + block) & (lane < held - p)
+        got = blk[jnp.clip(wk - start, 0, block - 1)]
+        got = jnp.pad(got, ((0, 0), (0, width - dim)))
+        out = out.at[jnp.where(take, wp, total)].set(got, mode="drop")
+        return p + jnp.sum(take, dtype=jnp.int32), out
+
+    # rows padded to whole lanes: a row-major buffer then costs what a
+    # column-major one would, and the compiler keeps one layout of it
+    width = -(-dim // 128) * 128
+    _, out = jax.lax.while_loop(
+        lambda state: state[0] < held, step,
+        (jnp.int32(0), jnp.zeros((total, width), xs.dtype)))
+    return out
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _score_row_sharded(queries, dataset, candidates, k: int,
+                       metric_val: int, mesh, axis: str):
+    """:func:`_score_fetched` over rows sharded by row over ``axis``:
+    each chip scores the candidates it holds (ids clamped into [0, n)
+    as the one-device gather clamps them), 0 elsewhere; a ``psum``
+    joins the scores, then invalid ids sink and the top-k is taken.
+    The device stage is named ``sharded.rerank``."""
+    n = dataset.shape[0]
+    metric = DistanceType(metric_val)
+
+    def local(q, xs, cand):
+        with jax.named_scope("sharded.rerank"):
+            compute = jnp.promote_types(q.dtype, jnp.float32)
+            q = q.astype(compute)
+            own = (jnp.clip(cand, 0, n - 1)
+                   - jax.lax.axis_index(axis) * xs.shape[0])
+            mine = (own >= 0) & (own < xs.shape[0])
+            vecs = _gather_held_rows(xs, own, mine)
+            m, c = cand.shape
+            # scored a block of queries at a time: the score's operand
+            # layout differs from the gather's, and a copy of every
+            # gathered row at once would double them
+            mb = next(b for b in range(max(min(m, _SCORE_ROWS // c), 1),
+                                       0, -1) if m % b == 0)
+
+            def score(b):
+                qb = jax.lax.dynamic_slice_in_dim(q, b * mb, mb)
+                vb = jax.lax.dynamic_slice_in_dim(vecs, b * mb * c, mb * c)
+                vb = vb[:, :xs.shape[1]].reshape(mb, c, -1)
+                return _exact_scores(qb, vb.astype(compute), metric)
+
+            d = jax.lax.map(score, jnp.arange(m // mb))
+            d = jax.lax.psum(jnp.where(mine, d.reshape(m, c), 0.0), axis)
+            return _select_scored(d, cand, k, metric, compute)
+
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(P(), P(axis, None), P()),
+        out_specs=(P(), P()), check_vma=False,
+    )(queries, dataset, candidates)
+
+
 @jax.jit
 def _promote_scatter(hot_block, miss_block, src_pos, dst_slot):
     """Build the successor hot block: promoted rows scattered in FROM
@@ -619,7 +745,8 @@ def as_source(dataset, hot_rows: Optional[int] = None) -> RerankSource:
     * a source instance passes through (the persistent-hot-cache path);
     * a device ``jax.Array`` keeps the full-upload
       :class:`DeviceSource` fast path (back-compat: an
-      already-uploaded dataset is never re-tiered);
+      already-uploaded dataset is never re-tiered), or, when its rows
+      are sharded over a mesh axis, :class:`RowShardedSource`;
     * a host ``np.ndarray`` / ``np.memmap`` becomes a
       :class:`HostArraySource` — per-call, so the hot cache defaults
       OFF here (``hot_rows=0``); construct the source yourself to keep
@@ -629,11 +756,32 @@ def as_source(dataset, hot_rows: Optional[int] = None) -> RerankSource:
     if isinstance(dataset, RerankSource):
         return dataset
     if isinstance(dataset, jax.Array):
+        split = _row_split(dataset)
+        if split is not None:
+            return RowShardedSource(dataset, *split)
         return DeviceSource(dataset)
     if isinstance(dataset, np.ndarray):
         return HostArraySource(
             dataset, hot_rows=0 if hot_rows is None else hot_rows)
     return DeviceSource(jnp.asarray(dataset))
+
+
+def _row_split(dataset) -> Optional[tuple]:
+    """(mesh, axis) when ``dataset`` is a 2-d array whose rows alone are
+    split, evenly, over one axis of a mesh of more than one device."""
+    sh = dataset.sharding
+    if not isinstance(sh, NamedSharding) or dataset.ndim != 2:
+        return None
+    spec = tuple(sh.spec) + (None, None)
+    axis = spec[0]
+    if isinstance(axis, tuple):
+        axis = axis[0] if len(axis) == 1 else None
+    if axis is None or spec[1] is not None:
+        return None
+    parts = sh.mesh.shape[axis]
+    if parts < 2 or dataset.shape[0] % parts:
+        return None
+    return sh.mesh, axis
 
 
 def memmap_source(path: str, dim: Optional[int] = None, dtype=None,
@@ -663,5 +811,5 @@ def memmap_source(path: str, dim: Optional[int] = None, dtype=None,
 __all__ = [
     "DEFAULT_HOT_ROWS", "DeviceSource", "FetchInfo", "HOT_ROWS_BUDGET",
     "HostArraySource", "PROMOTE_BATCH", "RUNG_FLOOR", "RerankSource",
-    "as_source", "memmap_source",
+    "RowShardedSource", "as_source", "memmap_source",
 ]

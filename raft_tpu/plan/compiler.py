@@ -662,29 +662,28 @@ def _build_collective_merge(node, binds, plan):
         hook = ctx.extra.get("pre_merge")
         if hook is not None:
             d, i = hook(d, i)
-        gd = jax.lax.all_gather(d, axis, axis=1, tiled=True)
-        gi = jax.lax.all_gather(i, axis, axis=1, tiled=True)
-        return merge_topk(gd, gi, int(k), select_min)
+        with jax.named_scope("sharded.merge"):
+            gd = jax.lax.all_gather(d, axis, axis=1, tiled=True)
+            gi = jax.lax.all_gather(i, axis, axis=1, tiled=True)
+            return merge_topk(gd, gi, int(k), select_min)
     return run
 
 
 @register_op("rerank", "tiered.rerank")
 def _build_tiered_rerank_tail(node, binds, plan):
     """Router-side tiered rerank over an already-merged id shortlist
-    (the sharded tail: only the merged shortlist's unique rows are
-    fetched, host-side of the collective)."""
-    src = binds.source
-    if src is None:
-        raise PlanError(f"node {node.id!r}: rerank source not bound")
-    index = binds.index
+    (the sharded tail: only the merged shortlist's rows are read,
+    router-side of the collective). The source comes per call as
+    ``extra={"source": ...}`` and the metric among the compile statics,
+    so that a caller may hold the compiled tail across calls without
+    holding a call's rows."""
+    metric = binds.extra["metric"]
     k = _resolve_width(node, binds)
     by_id = {n.id: n for n in plan.nodes}
 
     def run(ctx):
         _, ids = _candidate_inputs(ctx, node, by_id)[0]
-        with obs.span("sharded_ivf_pq.tiered_rerank",
-                      kc=int(np.shape(ids)[-1])):
-            return src.rerank(ctx.queries, ids, int(k), index.metric)
+        return ctx.extra["source"].rerank(ctx.queries, ids, int(k), metric)
     return run
 
 
